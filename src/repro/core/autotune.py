@@ -51,8 +51,9 @@ from repro.core.types import Engine, SignatureLayout, TopKMethod
 # ---------------------------------------------------------------------------
 
 CACHE_VERSION = 1
-# Mirrors tools/genielint config.vmem_budget_bytes: candidate tiles whose
-# estimated VMEM working set exceeds this are never even measured.
+# Candidate tiles whose estimated VMEM working set (_vmem_estimate) exceeds
+# this are never even measured: 12 MiB of the chip's 16 MiB scoped-VMEM
+# limit, the rest left to the kernel's temporaries.
 VMEM_BUDGET_BYTES = 12 * 1024 * 1024
 
 _CACHE_ENV = "GENIE_AUTOTUNE_CACHE"
@@ -429,16 +430,41 @@ def _effective_tile(size: int, preferred: int, align: int) -> int:
     return pick_tile(size, preferred, align)
 
 
-def _vmem_estimate(tiles: dict, q: int, n: int, width: int) -> int:
-    """Rough per-grid-step VMEM working set: a [tile_q, W] query window, a
-    [tile_n, W] data window, and the [tile_q, tile_n] count tile, int32.
-    Conservative on purpose -- it only prunes candidates, never admits."""
-    tq = tiles.get("tile_q", 128)
-    tn = tiles.get("tile_n", 256)
-    w = min(width, tiles.get("tile_v", tiles.get("tile_m", width)))
-    tq = min(tq, max(q, 8))
-    tn = min(tn, max(n, 128))
-    return 4 * (tq * w + tn * w + tq * tn)
+def _vmem_estimate(tiles: dict, q: int, n: int, width: int, *,
+                   sweep: bool = True, query_parts: int = 1) -> int:
+    """Per-grid-step VMEM working set in bytes, as the kernels lay it out.
+
+    The column-sweep kernels (every VPU count kernel) hold `query_parts`
+    grouped query blocks [Wb/8, tile_q, 8], whose 8-wide minor axis pads to
+    128 lanes, and a transposed data block [Wb, tile_n]; the MXU kernels
+    (IP, wide COSINE) hold [tile_q, Wb] and [tile_n, Wb] blocks instead.
+    Wb is the width one grid step sees (tile_v / tile_m where the kernel
+    chunks it).  The Pallas pipeline double-buffers every block and its
+    [tile_q, tile_n] out block, and the kernel adds a [tile_q, tile_n]
+    accumulator.  Four bytes a word throughout, which over-counts the 8-bit
+    kernels: conservative on purpose -- it only prunes, never admits."""
+    tq = _ceil_to(min(tiles.get("tile_q", 128), max(q, 8)), 8)
+    tn = _ceil_to(min(tiles.get("tile_n", 256), max(n, 128)), 128)
+    wb = _ceil_to(min(width, tiles.get("tile_v", tiles.get("tile_m", width))), 8)
+    if sweep:
+        q_block = query_parts * (wb // 8) * tq * 128
+        d_block = wb * tn
+    else:
+        q_block = tq * _ceil_to(wb, 128)
+        d_block = tn * _ceil_to(wb, 128)
+    out = tq * tn
+    return 4 * (2 * (q_block + d_block + out) + out)
+
+
+def _ceil_to(x: int, multiple: int) -> int:
+    return -(-x // multiple) * multiple
+
+
+def _is_sweep(engine: Engine, layout: SignatureLayout) -> bool:
+    """True when the engine's kernel walks signature columns on the VPU
+    (common.column_sweep); False for the MXU kernels."""
+    return not (engine in (Engine.IP, Engine.COSINE)
+                and layout is SignatureLayout.WIDE)
 
 
 def tile_candidates(knob: str, dim: int, *,
@@ -535,6 +561,8 @@ def tune(engine: Engine | str | _engines.MatchModel, data, queries, k: int,
     if sig_layout is SignatureLayout.PACKED:
         knobs = knobs | model.tile_knobs(True, sig_layout, fused=True)
     dims = {"tile_q": n_q, "tile_n": n, "tile_v": width, "tile_m": width}
+    sweep = _is_sweep(model.engine, sig_layout)
+    query_parts = 2 if model.engine is Engine.RANGE else 1   # lo and hi
 
     state = {
         "tiles": {}, "fused": None, "candidate_cap": None,
@@ -590,7 +618,8 @@ def tune(engine: Engine | str | _engines.MatchModel, data, queries, k: int,
         for cand in tile_candidates(knob, dims[knob]):
             tiles = dict(best["tiles"])
             tiles[knob] = cand
-            if _vmem_estimate(tiles, n_q, n, width) > vmem_budget:
+            if _vmem_estimate(tiles, n_q, n, width, sweep=sweep,
+                              query_parts=query_parts) > vmem_budget:
                 continue
             try_state(dict(best, tiles=tiles))
 

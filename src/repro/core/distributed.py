@@ -39,11 +39,6 @@ from repro.core import engines as _engines
 from repro.core import plan as _plan
 from repro.core.types import Engine, SearchParams, SignatureLayout, TopKResult
 
-# Back-compat re-exports: the version-portable shard_map shims moved into the
-# executor module with the shard_map body itself.
-shard_map_compat = _plan.shard_map_compat
-shard_linear_index = _plan._shard_linear_index
-
 MatchLike = Union[Engine, str, "_engines.MatchModel",
                   Callable[[jnp.ndarray, Any], jnp.ndarray]]
 

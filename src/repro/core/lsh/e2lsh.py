@@ -49,7 +49,11 @@ def make(key, d: int, m: int, w: float, p: int = 2, n_buckets: int = 8192) -> E2
 
 def raw_hash(params: E2LSHParams, x: jnp.ndarray) -> jnp.ndarray:
     """floor((a.x + b)/w) -> int32 [..., m] (pre-rehash bucket coordinates)."""
-    proj = jnp.einsum("...d,md->...m", x.astype(jnp.float32), params.a)
+    # HIGHEST: the TPU's default f32 matmul rounds its inputs to bf16, which
+    # moves projections across bucket edges; full f32 hashes the same
+    # function on every backend
+    proj = jnp.einsum("...d,md->...m", x.astype(jnp.float32), params.a,
+                      precision=jax.lax.Precision.HIGHEST)
     return jnp.floor((proj + params.b) / params.w).astype(jnp.int32)
 
 

@@ -30,7 +30,10 @@ def make(key, d: int, m: int) -> SimHashParams:
 
 
 def hash_points(params: SimHashParams, x: jnp.ndarray) -> jnp.ndarray:
-    proj = jnp.einsum("...d,md->...m", x.astype(jnp.float32), params.v)
+    # HIGHEST: full f32 projections, so a sign is the same on every backend
+    # (the TPU's default rounds matmul inputs to bf16; see e2lsh.raw_hash)
+    proj = jnp.einsum("...d,md->...m", x.astype(jnp.float32), params.v,
+                      precision=jax.lax.Precision.HIGHEST)
     return (proj >= 0).astype(jnp.int32)
 
 

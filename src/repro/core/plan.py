@@ -62,17 +62,6 @@ from repro.core.select import select_topk
 from repro.core.types import (Engine, SearchParams, SignatureLayout,
                               TopKMethod, TopKResult)
 
-# jax >= 0.6 promotes shard_map to the top level (keyword `check_vma`);
-# earlier releases keep it in jax.experimental (keyword `check_rep`).
-try:
-    from jax import shard_map as _shard_map  # type: ignore[attr-defined]
-
-    _CHECK_KW = "check_vma"
-except ImportError:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 MatchLike = Union[Engine, str, "_engines.MatchModel",
                   Callable[[jnp.ndarray, Any], jnp.ndarray]]
 
@@ -445,14 +434,15 @@ def _mask_invalid(gids: jnp.ndarray, counts: jnp.ndarray, n_objects: Optional[in
     return jnp.where(valid, gids, -1), jnp.where(valid, counts, -1)
 
 
-def pad_to_multiple(data: jnp.ndarray, multiple: int, pad_value) -> tuple[jnp.ndarray, int]:
-    """(padded data, true row count): append engine-fill rows up to the next
-    multiple (mesh divisibility, even part splits)."""
+def pad_to_multiple(data: np.ndarray, multiple: int, pad_value) -> tuple[np.ndarray, int]:
+    """(padded host data, true row count): append engine-fill rows up to the
+    next multiple (mesh divisibility).  Host-side, so a corpus bound for a
+    sharded placement never gathers on one device."""
     n = int(data.shape[0])
     pad = (-n) % max(int(multiple), 1)
     if pad:
-        fill = jnp.full((pad,) + data.shape[1:], pad_value, dtype=data.dtype)
-        data = jnp.concatenate([data, fill], axis=0)
+        fill = np.full((pad,) + data.shape[1:], pad_value, dtype=data.dtype)
+        data = np.concatenate([data, fill], axis=0)
     return data, n
 
 
@@ -793,16 +783,16 @@ def _build_sharded(plan: QueryPlan, mesh: jax.sharding.Mesh, key):
     out_specs = TopKResult(ids=P(None, None), counts=P(None, None),
                            threshold=P(None))
     if routed:
-        sharded = shard_map_compat(
-            _local, mesh,
+        sharded = jax.shard_map(
+            _local, mesh=mesh,
             in_specs=(P(axes), P(None, None), P(None)),
-            out_specs=out_specs,
+            out_specs=out_specs, check_vma=False,
         )
     else:
-        sharded = shard_map_compat(
-            lambda data_local, queries: _local(data_local, queries), mesh,
+        sharded = jax.shard_map(
+            lambda data_local, queries: _local(data_local, queries), mesh=mesh,
             in_specs=(P(axes), P(None, None)),
-            out_specs=out_specs,
+            out_specs=out_specs, check_vma=False,
         )
     return jax.jit(sharded)
 
@@ -893,26 +883,12 @@ def execute(plan: QueryPlan, data, queries,
 
 
 # ---------------------------------------------------------------------------
-# Mesh helpers shared with core/distributed (which re-exports them)
+# Mesh helpers
 # ---------------------------------------------------------------------------
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """Version-portable shard_map with replication checking disabled."""
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: False})
-
-
-def _axis_size(name: str) -> jnp.ndarray:
-    # jax.lax.axis_size is newer-jax; psum(1) is its portable equivalent
-    # (constant-folded at trace time).
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
-
 
 def _shard_linear_index(axes: tuple[str, ...]) -> jnp.ndarray:
     """Linearised shard index over the given mesh axes (row-major)."""
     idx = jnp.int32(0)
     for name in axes:
-        idx = idx * _axis_size(name) + jax.lax.axis_index(name)
+        idx = idx * jax.lax.axis_size(name) + jax.lax.axis_index(name)
     return idx

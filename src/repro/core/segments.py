@@ -38,6 +38,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import engines as _engines
 from repro.core import plan as _plan
@@ -85,6 +86,10 @@ class SegmentedIndex:
     compaction_seconds: float = 0.0
     # storage format every segment is sealed into (core/packing.py)
     signature_layout: SignatureLayout = SignatureLayout.WIDE
+    # keep sealed segments in host memory: for a caller that serves from the
+    # sharded placement concat_data() feeds, so that no one device ever
+    # holds the whole corpus
+    host_resident: bool = False
 
     def __post_init__(self):
         self.signature_layout = self.model.require_layout(self.signature_layout)
@@ -133,8 +138,6 @@ class SegmentedIndex:
     def add(self, raw_data) -> GenieIndex:
         """Seal one batch into a new immutable segment: O(batch) device work,
         no re-hash or re-upload of earlier segments."""
-        import numpy as np
-
         shape = np.shape(raw_data)
         if not shape or shape[0] == 0:
             # an empty segment would poison every later search (0-row match)
@@ -151,6 +154,8 @@ class SegmentedIndex:
                 )
         if self.max_count is None:
             self.max_count = seg.max_count
+        if self.host_resident:
+            seg.data = np.asarray(seg.data)
         self.segments.append(seg)
         return seg
 
@@ -284,7 +289,8 @@ class SegmentedIndex:
             # record a negative compaction duration
             t0 = time.perf_counter()
             a, b = segs[i].stats, segs[i + 1].stats
-            arr = jnp.concatenate([segs[i].data, segs[i + 1].data], axis=0)
+            concat = np.concatenate if self.host_resident else jnp.concatenate
+            arr = concat([segs[i].data, segs[i + 1].data], axis=0)
             jax.block_until_ready(arr)
             t_total += time.perf_counter() - t0
             # aggregate the sources' stats instead of recomputing on `arr`:
@@ -326,14 +332,15 @@ class SegmentedIndex:
     # ------------------------------------------------------------------
     # Export for the distributed (sharded) layout
     # ------------------------------------------------------------------
-    def concat_data(self, pad_multiple: int = 1) -> tuple[jnp.ndarray, int]:
+    def concat_data(self, pad_multiple: int = 1) -> tuple[np.ndarray, int]:
         """(data, n_objects) for the distributed shard layout: segments
-        concatenated in global-id order, row count padded up to a multiple of
-        `pad_multiple` with the engine's pad fill.  Pass `n_objects` to
-        `distributed.make_search_step` so pad rows are masked out of every
-        shard's candidate buffer."""
+        concatenated on the host in global-id order, row count padded up to
+        a multiple of `pad_multiple` with the engine's pad fill.  A sharded
+        `jax.device_put` of the result sends each device only its shard.
+        Pass `n_objects` to `distributed.make_search_step` so pad rows are
+        masked out of every shard's candidate buffer."""
         if not self.segments:
             raise ValueError("empty SegmentedIndex: add() first")
-        data = jnp.concatenate([s.data for s in self.segments], axis=0)
+        data = np.concatenate([np.asarray(s.data) for s in self.segments], axis=0)
         return _plan.pad_to_multiple(
             data, pad_multiple, self.model.pad_value_for(self.signature_layout))

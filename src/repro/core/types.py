@@ -79,7 +79,7 @@ class SearchParams:
     max_count: int                 # count-domain bound (e.g. m for LSH, #attrs for tables)
     method: TopKMethod = TopKMethod.CPQ
     candidate_cap: Optional[int] = None  # capacity of the candidate buffer (default 2k)
-    use_kernel: bool = True        # Pallas kernels (interpret=True off-TPU) vs pure jnp
+    use_kernel: bool = True        # Pallas kernels (interpreted on CPU) vs pure jnp
 
     def cap(self) -> int:
         if self.candidate_cap is not None:

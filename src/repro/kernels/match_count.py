@@ -2,68 +2,64 @@
 
 counts[q, n] = sum_i (data_sigs[n, i] == query_sigs[q, i])
 
-This is GENIE's inverted-index scan re-expressed for the TPU (DESIGN.md
-section 2): instead of scanning postings lists with atomic counter updates,
-each grid cell compares a [TILE_Q, m] query-signature block against a
-[TILE_N, m] data-signature block held in VMEM and emits a dense [TILE_Q,
-TILE_N] count tile.  The compare runs on the VPU in m/CHUNK vectorised steps;
-the signature matrix streams from HBM exactly once per query tile, giving the
-memory-bound roofline analysed in EXPERIMENTS.md.
+This is GENIE's inverted-index scan re-expressed for the TPU: instead of
+scanning postings lists with atomic counter updates, each grid cell compares
+a query-signature block against a data-signature block held in VMEM and
+emits a dense [TILE_Q, TILE_N] count tile.  The compare walks the signature
+columns with `common.column_sweep`: one 2-D [TQ, TN] compare per column, in
+a loop whose body does not grow with m.  The wrapper (ops.py) hands it the
+grouped query and transposed data layouts that sweep reads.
 
-Grid: (Q/TILE_Q, N/TILE_N); each cell is independent (embarrassingly
-parallel -- the TPU analogue of the paper's "one block per query item" with
-perfect load balance by construction).
+Grid: (Q/TILE_Q, N/TILE_N); each cell is independent.  The query block's
+index does not change along the inner N axis, so it is fetched once per
+query tile.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import common
+
 TILE_Q = 128   # query rows per grid cell
 TILE_N = 256   # objects per grid cell (minor-most in the output tile)
-CHUNK = 8      # hash functions folded per vector step ([TQ, TN, CHUNK] temp)
 
 
-def _match_count_kernel(q_ref, d_ref, o_ref, *, m: int, chunk: int):
-    q = q_ref[...]  # [TQ, Mp] int32
-    d = d_ref[...]  # [TN, Mp] int32
-    acc = jnp.zeros((q.shape[0], d.shape[0]), dtype=jnp.int32)
-    for s in range(0, m, chunk):  # static unroll over signature chunks
-        e = min(s + chunk, m)
-        qs = q[:, s:e]
-        ds = d[:, s:e]
-        hit = qs[:, None, :] == ds[None, :, :]             # [TQ, TN, c]
-        acc = acc + jnp.sum(hit.astype(jnp.int32), axis=-1)
-    o_ref[...] = acc
+def _eq(q, d):
+    return (q == d).astype(jnp.int32)
+
+
+def _match_count_kernel(q_ref, d_ref, o_ref):
+    acc = jnp.zeros(o_ref.shape, dtype=jnp.int32)
+    o_ref[...] = common.column_sweep([q_ref], d_ref, _eq, acc)
 
 
 def match_count_pallas(
-    data_sigs: jnp.ndarray,
-    query_sigs: jnp.ndarray,
+    data_t: jnp.ndarray,
+    query_groups: jnp.ndarray,
     *,
     tile_q: int = TILE_Q,
     tile_n: int = TILE_N,
-    chunk: int = CHUNK,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """counts int32 [Q, N].  Inputs must already be padded: Q % tile_q == 0,
-    N % tile_n == 0 (ops.py handles padding/slicing)."""
-    qn, m = query_sigs.shape
-    nn = data_sigs.shape[0]
+    """counts int32 [Q, N] from transposed data int32 [Mp, N] and grouped
+    queries int32 [Mp/GROUP, Q, GROUP].  Inputs must already be padded and
+    laid out (ops.match_count does both): Q % tile_q == 0, N % tile_n == 0,
+    Mp % GROUP == 0, with distinct data/query sentinels in the pad."""
+    n_groups, qn, group = query_groups.shape
+    mp, nn = data_t.shape
+    assert group == common.GROUP and mp == n_groups * group, (query_groups.shape, mp)
     assert qn % tile_q == 0 and nn % tile_n == 0, (qn, nn, tile_q, tile_n)
     grid = (qn // tile_q, nn // tile_n)
-    kernel = functools.partial(_match_count_kernel, m=m, chunk=chunk)
     return pl.pallas_call(
-        kernel,
+        _match_count_kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((tile_q, m), lambda i, j: (i, 0)),
-            pl.BlockSpec((tile_n, m), lambda i, j: (j, 0)),
+            pl.BlockSpec((n_groups, tile_q, group), lambda i, j: (0, i, 0)),
+            pl.BlockSpec((mp, tile_n), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((tile_q, tile_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((qn, nn), jnp.int32),
         interpret=interpret,
-    )(query_sigs.astype(jnp.int32), data_sigs.astype(jnp.int32))
+    )(query_groups.astype(jnp.int32), data_t.astype(jnp.int32))

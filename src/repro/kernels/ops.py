@@ -1,9 +1,12 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Each wrapper pads inputs to tile multiples (with values that cannot produce
-spurious matches), dispatches to the kernel (interpret mode off-TPU), and
-slices the result back to logical shape.  These are the functions the GENIE
-engines call; repro.kernels.ref holds the oracles.
+spurious matches), lays them out the way the kernel reads them (the VPU
+count kernels take grouped queries and transposed data, see
+common.column_sweep), dispatches to the kernel (compiled on the TPU,
+interpreted on the CPU backend), and slices the result back to logical
+shape.  These are the functions the GENIE engines call; repro.kernels.ref
+holds the oracles.
 """
 from __future__ import annotations
 
@@ -34,6 +37,28 @@ def _tiles(q: int, n: int, tq_pref: int, tn_pref: int) -> tuple[int, int]:
     return tq, tn
 
 
+def _grouped_queries(query, *, tq: int, cols: int, pad,
+                     group: int = common.GROUP):
+    """Queries for common.column_sweep: rows padded to tq and columns to a
+    multiple of `cols` (itself a multiple of `group`) with `pad`, grouped
+    to [Mp/group, Qp, group]."""
+    q = common.pad_to(common.pad_to(query, tq, 0, pad), cols, 1, pad)
+    return q.reshape(q.shape[0], -1, group).transpose(1, 0, 2)
+
+
+def _transposed_data(data, *, tn: int, cols: int, pad):
+    """Data for common.column_sweep: rows padded to tn and columns to a
+    multiple of `cols` with `pad`, transposed to [Mp, Np]."""
+    return common.pad_to(common.pad_to(data, tn, 0, pad), cols, 1, pad).T
+
+
+def _sweep_layout(data, query, *, tq: int, tn: int, cols: int, d_pad, q_pad,
+                  group: int = common.GROUP):
+    """(transposed data, grouped queries) with distinct pad sentinels."""
+    return (_transposed_data(data, tn=tn, cols=cols, pad=d_pad),
+            _grouped_queries(query, tq=tq, cols=cols, pad=q_pad, group=group))
+
+
 @functools.partial(jax.jit, static_argnames=("tile_q", "tile_n", "interpret"))
 def match_count(
     data_sigs: jnp.ndarray,
@@ -47,8 +72,9 @@ def match_count(
     qn, m = query_sigs.shape
     nn = data_sigs.shape[0]
     tq, tn = _tiles(qn, nn, tile_q or _mc.TILE_Q, tile_n or _mc.TILE_N)
-    q = common.pad_to(query_sigs.astype(jnp.int32), tq, 0, _PAD_QUERY)
-    d = common.pad_to(data_sigs.astype(jnp.int32), tn, 0, _PAD_DATA)
+    d, q = _sweep_layout(data_sigs.astype(jnp.int32), query_sigs.astype(jnp.int32),
+                         tq=tq, tn=tn, cols=common.GROUP,
+                         d_pad=_PAD_DATA, q_pad=_PAD_QUERY)
     out = _mc.match_count_pallas(
         d, q, tile_q=tq, tile_n=tn, interpret=common.use_interpret(interpret)
     )
@@ -69,11 +95,12 @@ def range_count(
     qn, d = q_lo.shape
     nn = data_vals.shape[0]
     tq, tn = _tiles(qn, nn, tile_q or _rc.TILE_Q, tile_n or _rc.TILE_N)
-    # Padded queries use an empty range (lo > hi); padded data never matters
-    # because the output is sliced.
-    lo = common.pad_to(q_lo.astype(jnp.int32), tq, 0, 1)
-    hi = common.pad_to(q_hi.astype(jnp.int32), tq, 0, 0)
-    x = common.pad_to(data_vals.astype(jnp.int32), tn, 0, _PAD_DATA)
+    # Padded queries and attributes use an empty range (lo > hi); padded
+    # data rows never matter because the output is sliced.
+    x = _transposed_data(data_vals.astype(jnp.int32), tn=tn, cols=common.GROUP,
+                         pad=_PAD_DATA)
+    lo = _grouped_queries(q_lo.astype(jnp.int32), tq=tq, cols=common.GROUP, pad=1)
+    hi = _grouped_queries(q_hi.astype(jnp.int32), tq=tq, cols=common.GROUP, pad=0)
     out = _rc.range_count_pallas(
         x, lo, hi, tile_q=tq, tile_n=tn, interpret=common.use_interpret(interpret)
     )
@@ -95,8 +122,8 @@ def minsum_count(
     nn = data_cnt.shape[0]
     tq, tn = _tiles(qn, nn, tile_q or _ms.TILE_Q, tile_n or _ms.TILE_N)
     tv = common.pick_tile(v, tile_v or _ms.TILE_V, 128, knob="tile_v")
-    q = common.pad_to(common.pad_to(query_cnt.astype(jnp.int32), tq, 0, 0), tv, 1, 0)
-    d = common.pad_to(common.pad_to(data_cnt.astype(jnp.int32), tn, 0, 0), tv, 1, 0)
+    d, q = _sweep_layout(data_cnt.astype(jnp.int32), query_cnt.astype(jnp.int32),
+                         tq=tq, tn=tn, cols=tv, d_pad=0, q_pad=0)
     out = _ms.minsum_count_pallas(
         d, q, tile_q=tq, tile_n=tn, tile_v=tv, interpret=common.use_interpret(interpret)
     )
@@ -144,10 +171,8 @@ def tanimoto_count(
     tm = common.pick_tile(m, tile_m or _tc.TILE_M, 128, knob="tile_m")
     # Distinct sentinels on every padded axis: padded signature slots never
     # collide, padded rows/cols are sliced away.
-    q = common.pad_to(common.pad_to(query_sigs.astype(jnp.int32), tq, 0, _PAD_QUERY),
-                      tm, 1, _PAD_QUERY)
-    d = common.pad_to(common.pad_to(data_sigs.astype(jnp.int32), tn, 0, _PAD_DATA),
-                      tm, 1, _PAD_DATA)
+    d, q = _sweep_layout(data_sigs.astype(jnp.int32), query_sigs.astype(jnp.int32),
+                         tq=tq, tn=tn, cols=tm, d_pad=_PAD_DATA, q_pad=_PAD_QUERY)
     out = _tc.tanimoto_count_pallas(
         d, q, tile_q=tq, tile_n=tn, tile_m=tm, interpret=common.use_interpret(interpret)
     )
@@ -201,19 +226,27 @@ def packed_cosine_count(
 
     Inputs are int32 word matrices from core/packing.py (query tail bits 1,
     data tail bits 0).  Pad rows are all-zero words -- their counts are
-    garbage but sliced away; word-axis alignment is not needed because the
-    kernel chunks the packed width in VMEM.
+    garbage but sliced away; pad words are 0 on both sides, so XOR+popcount
+    adds nothing for them.
     """
     qn, w = query_words.shape
     nn = data_words.shape[0]
     tq, tn = _tiles(qn, nn, tile_q or _pcos.TILE_Q, tile_n or _pcos.TILE_N)
-    q = common.pad_to(query_words.astype(jnp.int32), tq, 0, 0)
-    d = common.pad_to(data_words.astype(jnp.int32), tn, 0, 0)
+    d, q = _sweep_layout(data_words.astype(jnp.int32), query_words.astype(jnp.int32),
+                         tq=tq, tn=tn, cols=common.GROUP, d_pad=0, q_pad=0)
     out = _pcos.packed_cosine_count_pallas(
         d, q, bits_total=32 * w, tile_q=tq, tile_n=tn,
         interpret=common.use_interpret(interpret)
     )
     return out[:qn, :nn]
+
+
+def _flatten_tiles(cand: jnp.ndarray, qn: int) -> jnp.ndarray:
+    """Fused-kernel candidates [n_tiles, Qp, kc] -> [qn, n_tiles * kc]: tile
+    j's (count desc, id asc) run lands at columns j*kc.., so the buffer
+    stays id-ascending within equal counts across tiles."""
+    n_tiles, qp, kc = cand.shape
+    return cand.transpose(1, 0, 2).reshape(qp, n_tiles * kc)[:qn]
 
 
 @functools.partial(jax.jit, static_argnames=("k", "tile_q", "tile_n", "interpret"))
@@ -236,13 +269,13 @@ def packed_cosine_topk(
     qn, w = query_words.shape
     nn = data_words.shape[0]
     tq, tn = _tiles(qn, nn, tile_q or _pcos.TILE_Q, tile_n or _pcos.TILE_N)
-    q = common.pad_to(query_words.astype(jnp.int32), tq, 0, 0)
-    d = common.pad_to(data_words.astype(jnp.int32), tn, 0, 0)
+    d, q = _sweep_layout(data_words.astype(jnp.int32), query_words.astype(jnp.int32),
+                         tq=tq, tn=tn, cols=common.GROUP, d_pad=0, q_pad=0)
     ids, cnts = _pcos.packed_cosine_topk_pallas(
         d, q, bits_total=32 * w, n_logical=nn, k=k, tile_q=tq, tile_n=tn,
         interpret=common.use_interpret(interpret)
     )
-    return ids[:qn], cnts[:qn]
+    return _flatten_tiles(ids, qn), _flatten_tiles(cnts, qn)
 
 
 @functools.partial(jax.jit, static_argnames=("tile_q", "tile_n", "tile_m", "interpret"))
@@ -255,15 +288,14 @@ def packed_tanimoto_count(
     tile_m: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """Packed TANIMOTO kernel: byte-lane collision counts int32 [Q, N]."""
+    """Packed TANIMOTO kernel: uint8 collision counts int32 [Q, N]."""
     qn, m = query_u8.shape
     nn = data_u8.shape[0]
     tq, tn = _tiles(qn, nn, tile_q or _ptan.TILE_Q, tile_n or _ptan.TILE_N)
     tm = common.pick_tile(m, tile_m or _ptan.TILE_M, 128, knob="tile_m")
-    q = common.pad_to(common.pad_to(query_u8.astype(jnp.uint8), tq, 0, _PAD_QUERY_U8),
-                      tm, 1, _PAD_QUERY_U8)
-    d = common.pad_to(common.pad_to(data_u8.astype(jnp.uint8), tn, 0, _PAD_DATA_U8),
-                      tm, 1, _PAD_DATA_U8)
+    d, q = _sweep_layout(data_u8.astype(jnp.uint8), query_u8.astype(jnp.int32),
+                         tq=tq, tn=tn, cols=tm, d_pad=_PAD_DATA_U8,
+                         q_pad=_PAD_QUERY_U8, group=common.GROUP_BYTES)
     out = _ptan.packed_tanimoto_count_pallas(
         d, q, tile_q=tq, tile_n=tn, tile_m=tm, interpret=common.use_interpret(interpret)
     )
@@ -285,13 +317,14 @@ def packed_tanimoto_topk(
     qn, m = query_u8.shape
     nn = data_u8.shape[0]
     tq, tn = _tiles(qn, nn, tile_q or _ptan.TILE_Q, tile_n or _ptan.TILE_N)
-    q = common.pad_to(query_u8.astype(jnp.uint8), tq, 0, _PAD_QUERY_U8)
-    d = common.pad_to(data_u8.astype(jnp.uint8), tn, 0, _PAD_DATA_U8)
+    d, q = _sweep_layout(data_u8.astype(jnp.uint8), query_u8.astype(jnp.int32),
+                         tq=tq, tn=tn, cols=common.GROUP_BYTES, d_pad=_PAD_DATA_U8,
+                         q_pad=_PAD_QUERY_U8, group=common.GROUP_BYTES)
     ids, cnts = _ptan.packed_tanimoto_topk_pallas(
         d, q, n_logical=nn, k=k, tile_q=tq, tile_n=tn,
         interpret=common.use_interpret(interpret)
     )
-    return ids[:qn], cnts[:qn]
+    return _flatten_tiles(ids, qn), _flatten_tiles(cnts, qn)
 
 
 @functools.partial(jax.jit, static_argnames=("max_count", "tile_q", "tile_n", "interpret"))

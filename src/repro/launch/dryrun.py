@@ -120,7 +120,7 @@ def _lower_lm(cfg, shape, mesh, accum_override=None):
     api = get_api(cfg)
     # training uses the per-arch DP/TP choice; serving always uses TP
     use_tp = cfg.use_tp if shape.kind == "train" else cfg.use_tp_serve
-    with mesh_lib.use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         batch_sds = shapes_lib.input_specs(cfg, shape)
         batch_sh = sh_lib.batch_shardings(batch_sds, mesh, use_tp)
         params_shapes = jax.eval_shape(lambda: api.init_params(cfg, jax.random.PRNGKey(0)))
@@ -325,7 +325,7 @@ def run_genie_cell(dataset: str, mesh_kind: str) -> dict:
         params = SearchParams(k=ds.default_k, max_count=ds.dim, use_kernel=False)
 
     t0 = time.perf_counter()
-    with mesh_lib.use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         # segmented shard layout: data is segments concatenated in global-id
         # order and padded up to mesh divisibility (SegmentedIndex.concat_data);
         # n_objects masks the ragged pad tail out of every shard's buffer.
@@ -382,7 +382,7 @@ def run_genie_cell(dataset: str, mesh_kind: str) -> dict:
     if entry is not None:
         rep["autotune"]["entry"] = entry.to_dict()
         t2 = time.perf_counter()
-        with mesh_lib.use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             tuned_plan = plan_lib.plan_search(
                 ds.engine, params.k, params.max_count,
                 layout=plan_lib.Layout.DISTRIBUTED, n_objects=ds.n_objects,

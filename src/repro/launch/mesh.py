@@ -9,31 +9,14 @@ any jax import; everything else sees the real device count).
 """
 from __future__ import annotations
 
-import contextlib
-
 import jax
 
 
 def make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """Version-portable jax.make_mesh: `axis_types` only exists on newer jax
-    (and Auto is already the default there); older releases reject the kwarg."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(
-            shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
-        )
-    return jax.make_mesh(shape, axes)
-
-
-@contextlib.contextmanager
-def use_mesh(mesh: jax.sharding.Mesh):
-    """Version-portable ambient mesh: jax.sharding.set_mesh on newer jax,
-    the Mesh context manager on older releases."""
-    if hasattr(jax.sharding, "set_mesh"):
-        with jax.sharding.set_mesh(mesh):
-            yield mesh
-    else:
-        with mesh:
-            yield mesh
+    """jax.make_mesh with every axis Auto-sharded."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes)
+    )
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -51,7 +51,7 @@ def main() -> None:
                        ckpt_every=args.ckpt_every, log_every=10)
     data = DataConfig(global_batch=args.global_batch, seq_len=args.seq)
 
-    with mesh_lib.use_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         pshapes = jax.eval_shape(lambda: api.init_params(cfg, jax.random.PRNGKey(0)))
         psh = sh_lib.params_shardings(pshapes, mesh, cfg.use_tp)
         ssh = sh_lib.state_shardings(

@@ -133,9 +133,12 @@ class RetrievalService:
             self._dim = int(emb.shape[-1])
             self._params = self._make_params(self._dim)
         if self._index is None:
+            # a meshed service serves from the sharded placement, so its
+            # sealed segments wait on the host
             self._index = SegmentedIndex(engine=self._scheme.engine,
                                          max_count=self.m,
-                                         signature_layout=self.signature_layout)
+                                         signature_layout=self.signature_layout,
+                                         host_resident=self.mesh is not None)
         self._index.add(self._hash(emb))
         self._items.extend(items)
         if len(self._index.segments) > self.max_segments:
@@ -166,6 +169,26 @@ class RetrievalService:
             data = jax.device_put(data, distributed.data_sharding(self.mesh))
             self._placed = (fp, data, n)
         return self._placed[1], self._placed[2]
+
+    def signatures(self, embeddings: np.ndarray) -> np.ndarray:
+        """Hash embeddings [n, d] with this service's LSH parameters, as
+        add() and search() do before the engine prepares them; fetched to
+        the host."""
+        if self._params is None:
+            raise ValueError("RetrievalService has no LSH parameters yet: "
+                             "call add() first")
+        return np.asarray(self._hash(self._embed(None, embeddings)))
+
+    def corpus_signatures(self) -> np.ndarray:
+        """The stored (engine-prepared) signatures [n, width] in global-id
+        order, fetched to the host: from the sharded placement on a meshed
+        service, from the sealed segments otherwise."""
+        if self._index is None:
+            raise ValueError("RetrievalService index is empty (no items added yet)")
+        if self.mesh is not None:
+            data, n = self._sharded_corpus()
+            return np.asarray(data)[:n]
+        return self._index.concat_data()[0]
 
     def _router(self) -> routing_lib.Router:
         """Router over the current segments' summaries, cached until the

@@ -14,7 +14,7 @@ def _run(code: str) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", textwrap.dedent(code)],
         capture_output=True, text=True, env=env, timeout=600,
@@ -65,7 +65,7 @@ def test_sharded_train_step_matches_single_device():
         l0 = float(loss_single(params, batch)[0])
 
         mesh = sh_lib_mesh.make_mesh((4, 2), ('data', 'model'))
-        with sh_lib_mesh.use_mesh(mesh):
+        with jax.sharding.set_mesh(mesh):
             pshapes = jax.eval_shape(lambda: api.init_params(cfg, jax.random.PRNGKey(0)))
             psh = sh_lib.params_shardings(pshapes, mesh, cfg.use_tp)
             bsh = sh_lib.batch_shardings({k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in batch.items()}, mesh, cfg.use_tp)

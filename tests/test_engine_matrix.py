@@ -85,7 +85,7 @@ def test_matrix_distributed_parity():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import distributed, engines, cpq

@@ -178,6 +178,49 @@ def test_pallas_vmem_budget_is_configurable(tmp_path):
                      vmem_budget_bytes=32 * 1024 * 1024) == []
 
 
+def test_pallas_grouped_and_squeezed_blocks(tmp_path):
+    # the column-sweep layout: a grouped query block splits one signature
+    # width over two unfoldable dims, and a None dim is squeezed
+    sweep = _kernel_fixture("""
+        def sweep_topk(query_groups, data_t, kc):
+            n_groups, qn, group = query_groups.shape
+            mp, nn = data_t.shape
+            return pl.pallas_call(
+                _kernel,
+                grid=(qn // TILE, nn // TILE),
+                in_specs=[
+                    pl.BlockSpec((n_groups, TILE, group), lambda i, j: (0, i, 0)),
+                    pl.BlockSpec((mp, TILE), lambda i, j: (0, j)),
+                ],
+                out_specs=pl.BlockSpec((None, TILE, kc), lambda i, j: (j, i, 0)),
+                out_shape=jax.ShapeDtypeStruct((4, qn, kc), jnp.int32),
+            )(query_groups.astype(jnp.int32), data_t.astype(jnp.int32))
+    """)
+    root = _tree(tmp_path, {
+        "repro/kernels/sweep.py": sweep,
+        # violation: two in_specs for one applied operand
+        "repro/kernels/short.py": _kernel_fixture("""
+            def short_count(q):
+                return pl.pallas_call(
+                    _kernel,
+                    grid=(1,),
+                    in_specs=[pl.BlockSpec((8, 8), lambda i: (0, 0)),
+                              pl.BlockSpec((8, 8), lambda i: (0, 0))],
+                    out_specs=pl.BlockSpec((8, 8), lambda i: (0, 0)),
+                    out_shape=jax.ShapeDtypeStruct((8, 8), jnp.int32),
+                )(q.astype(jnp.int32))
+        """),
+    })
+    got = _findings(root, "pallas-kernel-contract")
+    assert [f.path for f in got] == ["repro/kernels/short.py"]
+    assert "2 in_specs but 1 operands applied" in got[0].message
+    # three 512 x 128 x 4 B blocks: 786432 B; one byte under it fails
+    tight = _findings(root, "pallas-kernel-contract",
+                      vmem_budget_bytes=3 * 512 * 128 * 4 - 1)
+    assert any(f.path == "repro/kernels/sweep.py" and "786432" in f.message
+               for f in tight)
+
+
 # ---------------------------------------------------------------------------
 # retrace-hygiene
 # ---------------------------------------------------------------------------

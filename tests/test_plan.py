@@ -386,7 +386,7 @@ def test_planner_distributed_parity():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import cpq, distributed, engines
@@ -439,7 +439,7 @@ def test_planner_distributed_packed_parity():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import SegmentedIndex, cpq, distributed, engines
@@ -489,7 +489,7 @@ def test_retrieval_service_sharded_serving_parity():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     code = textwrap.dedent("""
         import numpy as np, jax
         from repro.launch import mesh as mesh_lib
@@ -506,6 +506,13 @@ def test_retrieval_service_sharded_serving_parity():
             for a, b in [(0, 30), (30, 37), (37, 90), (90, 130)]:
                 single.add(list(range(a, b)), embeddings=pts[a:b])
                 sharded.add(list(range(a, b)), embeddings=pts[a:b])
+            assert all(isinstance(s.data, np.ndarray)
+                       for s in sharded._index.segments), 'segment on a device'
+            assert np.array_equal(single.corpus_signatures(),
+                                  sharded.corpus_signatures()), scheme
+            if scheme != 'simhash':     # COSINE stores signs, not the bits
+                assert np.array_equal(single.signatures(pts),
+                                      single.corpus_signatures()), scheme
             q = pts[88:96] + 0.01
             r1, s1 = single.search(None, k=5, embeddings=q)
             r2, s2 = sharded.search(None, k=5, embeddings=q)

@@ -461,7 +461,7 @@ def test_distributed_routing_parity():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     code = textwrap.dedent("""
         import numpy as np, jax
         from repro.core import SegmentedIndex, cpq, distributed, engines
@@ -523,7 +523,7 @@ def test_distributed_service_routing_parity():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     code = textwrap.dedent("""
         import numpy as np
         from repro.core import cpq as cpq_lib

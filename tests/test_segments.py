@@ -86,6 +86,25 @@ def test_segmented_after_compaction(engine):
     assert seg.compaction_count == 2
 
 
+def test_host_resident_segments_stay_on_host():
+    """host_resident keeps every sealed and compacted segment a NumPy array,
+    and searches and exports exactly like the device-resident index."""
+    model, raw, queries, mc = _case(Engine.EQ)
+    mono = GenieIndex.build(Engine.EQ, raw, max_count=mc, use_kernel=False)
+    seg = SegmentedIndex(engine=Engine.EQ, max_count=mc, use_kernel=False,
+                         host_resident=True)
+    for a, b in zip(CUTS, CUTS[1:]):
+        seg.add(raw[a:b])
+    assert all(type(s.data) is np.ndarray for s in seg.segments)
+    _assert_same(seg.search(queries, k=9), mono.search(queries, k=9), "host")
+    seg.compact(2)
+    assert all(type(s.data) is np.ndarray for s in seg.segments)
+    _assert_same(seg.search(queries, k=9), mono.search(queries, k=9), "compacted")
+    data, n = seg.concat_data()
+    np.testing.assert_array_equal(data, np.asarray(mono.data))
+    assert n == mono.stats.n_objects
+
+
 def test_segment_stats_accounting():
     model, raw, _, mc = _case(Engine.EQ)
     seg = _segmented(Engine.EQ, raw, mc)
@@ -276,7 +295,7 @@ def test_distributed_segmented_layout_parity():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import SegmentedIndex, distributed, engines, cpq

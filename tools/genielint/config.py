@@ -45,7 +45,9 @@ class LintConfig:
     # Conservative stand-in for tile dims the resolver cannot fold to a
     # constant (data-dependent widths like the signature length m): GENIE
     # signature/feature widths are <= 512 everywhere (configs/, packing
-    # word counts are 32x smaller still).
+    # word counts are 32x smaller still).  A block's unfoldable dims share
+    # this one width between them: the column-sweep kernels split it as
+    # (Mp/G, TQ, G).
     assume_dim: int = 512
     # The registry's count-dtype policy (core/engines.py::MatchModel): match
     # kernels accumulate and emit exact int32 counts; any narrowing happens

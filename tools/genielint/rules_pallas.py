@@ -9,9 +9,13 @@ corrupts counts silently, it does not crash):
   2. estimated VMEM tile footprint (sum over in/out specs of
      prod(block dims) x dtype bytes) stays under the configurable budget
      (--vmem-budget-mb).  Dims are folded from module constants, parameter
-     defaults, and local shape math; an unresolvable dim (e.g. the
-     data-dependent signature width m) conservatively assumes
-     `config.assume_dim`.
+     defaults, and local shape math; a `None` dim is squeezed (size 1).
+     The dims of one block that cannot be folded (e.g. the data-dependent
+     signature width m) together assume `config.assume_dim`: a grouped
+     layout such as (Mp/G, TQ, G) splits one width over two dims.  The
+     estimate sees neither lane padding nor in-kernel temporaries, so it
+     is a screen only: tests/test_tpu_compile.py compiles every kernel for
+     the chip, and the TPU compiler decides VMEM (docs/CONTRACTS.md).
   3. out_shape dtypes match the MatchModel registry's count-dtype policy
      (exact int32 accumulation; narrowing happens post-kernel via
      as_count_dtype).  A float out_shape reintroduces the 2^24 rounding
@@ -201,12 +205,18 @@ def check(module: LintModule, config: LintConfig) -> Iterable[Finding]:
                 shape = spec.args[0] if spec.args else None
                 dims: list[int] = []
                 if isinstance(shape, (ast.Tuple, ast.List)):
+                    unresolved = False
                     for el in shape.elts:
+                        if isinstance(el, ast.Constant) and el.value is None:
+                            continue  # squeezed dim: size 1
                         v = resolve(el)
                         if v is None:
-                            v = config.assume_dim
-                            assumed = True
-                        dims.append(v)
+                            unresolved = True
+                        else:
+                            dims.append(v)
+                    if unresolved:
+                        dims.append(config.assume_dim)
+                        assumed = True
                 n_in = len(in_specs)
                 if i < n_in:
                     dt = _operand_dtype(operands[i]) if i < len(operands) \

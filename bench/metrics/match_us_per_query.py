@@ -1,0 +1,9 @@
+"""Match kernel: device time of the engine's match kernel (the trace name
+the configuration gives as `match_kernel`) per query row answered."""
+
+
+def read(ctx):
+    ns = ctx.window.kernel_ns(ctx.cfg["match_kernel"])
+    if not ns or not ctx.rows:
+        return None
+    return ns * 1e-3 / ctx.rows

@@ -1,0 +1,9 @@
+"""Merge (core/merge.py): device time of the ops in the program's
+`genie.merge` scope, or of the eager modules launched inside its
+`genie.merge` span, per query row answered (bench/scopes.py)."""
+import scopes
+
+
+def read(ctx):
+    a = scopes.analyse(ctx.window)
+    return None if a is None else a.scope_per_row_us("genie.merge", ctx.rows)

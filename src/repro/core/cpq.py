@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.types import SearchParams, TopKResult
+from repro.runtime import tracing
 
 
 def count_histogram(counts: jnp.ndarray, max_count: int, bin_chunk: int = 8) -> jnp.ndarray:
@@ -57,6 +58,7 @@ def zipper_array(hist: jnp.ndarray) -> jnp.ndarray:
     return jnp.flip(jnp.cumsum(rev, axis=-1), axis=-1)
 
 
+@tracing.scoped(tracing.GATE)
 def audit_threshold(hist: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Gate: AT[q] = min{t >= 1 : ZA[t] < k} (== max_count+1 when none).
 
@@ -71,6 +73,7 @@ def audit_threshold(hist: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray
     return at, at - 1
 
 
+@tracing.scoped(tracing.COMPACT)
 def _compact_candidates(
     counts: jnp.ndarray, threshold: jnp.ndarray, cap: int
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
@@ -128,11 +131,13 @@ def cpq_select(
     None it is computed with the pure-jnp reference.
     """
     if hist is None:
-        hist = count_histogram(counts, params.max_count)
+        with tracing.scope(tracing.HIST):
+            hist = count_histogram(counts, params.max_count)
     _, threshold = audit_threshold(hist, params.k)
     cap = params.cap()
     cand_ids, cand_vals = _compact_candidates(counts, threshold, cap)
-    ids, vals = topk_from_candidates(cand_ids, cand_vals, params.k)
+    with tracing.scope(tracing.ORDER):
+        ids, vals = topk_from_candidates(cand_ids, cand_vals, params.k)
     return TopKResult(ids=ids, counts=vals, threshold=threshold)
 
 

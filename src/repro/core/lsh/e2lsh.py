@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.lsh import rehash as _rehash
+from repro.runtime import tracing
 
 
 @jax.tree_util.register_dataclass
@@ -57,6 +58,7 @@ def raw_hash(params: E2LSHParams, x: jnp.ndarray) -> jnp.ndarray:
     return jnp.floor((proj + params.b) / params.w).astype(jnp.int32)
 
 
+@tracing.scoped(tracing.HASH)
 def hash_points(params: E2LSHParams, x: jnp.ndarray) -> jnp.ndarray:
     """Full GENIE transform: signatures int32 [..., m] in [0, n_buckets)."""
     return _rehash.rehash(raw_hash(params, x), params.seeds, params.n_buckets)

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.lsh import rehash as _rehash
+from repro.runtime import tracing
 
 
 @jax.tree_util.register_dataclass
@@ -51,6 +52,7 @@ def hash_sets(params: MinHashParams, elements: jnp.ndarray, valid: jnp.ndarray) 
     return _rehash.rehash(mins.astype(jnp.int32), params.rehash_seeds, params.n_buckets)
 
 
+@tracing.scoped(tracing.HASH)
 def hash_points(params: MinHashParams, x: jnp.ndarray) -> jnp.ndarray:
     """MinHash dense vectors via their positive-support feature set.
 

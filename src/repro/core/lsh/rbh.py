@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.lsh import rehash as _rehash
+from repro.runtime import tracing
 
 
 @jax.tree_util.register_dataclass
@@ -49,6 +50,7 @@ def raw_hash(params: RBHParams, x: jnp.ndarray) -> jnp.ndarray:
     return jnp.floor((x - params.u) / params.g).astype(jnp.int32)
 
 
+@tracing.scoped(tracing.HASH)
 def hash_points(params: RBHParams, x: jnp.ndarray) -> jnp.ndarray:
     """Signatures int32 [..., m] in [0, n_buckets) (vector signature re-hashed)."""
     cells = raw_hash(params, x)  # [..., m, d]

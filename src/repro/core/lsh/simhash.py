@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import tracing
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -29,6 +31,7 @@ def make(key, d: int, m: int) -> SimHashParams:
     return SimHashParams(v=jax.random.normal(key, (m, d), dtype=jnp.float32))
 
 
+@tracing.scoped(tracing.HASH)
 def hash_points(params: SimHashParams, x: jnp.ndarray) -> jnp.ndarray:
     # HIGHEST: full f32 projections, so a sign is the same on every backend
     # (the TPU's default rounds matmul inputs to bf16; see e2lsh.raw_hash)

@@ -21,8 +21,10 @@ import jax.numpy as jnp
 
 from repro.core import cpq as _cpq
 from repro.core.types import TopKResult
+from repro.runtime import tracing
 
 
+@tracing.scoped(tracing.MERGE)
 def merge_topk(ids: jnp.ndarray, counts: jnp.ndarray, k: int) -> TopKResult:
     """Merge per-part results.  ids/counts: int32 [S, Q, kp] (part-LOCAL top-k,
     ids already globalised) -> overall top-k [Q, k]."""
@@ -33,6 +35,7 @@ def merge_topk(ids: jnp.ndarray, counts: jnp.ndarray, k: int) -> TopKResult:
     return TopKResult(ids=out_ids, counts=out_counts, threshold=out_counts[:, -1])
 
 
+@tracing.scoped(tracing.MERGE)
 def merge_ragged(ids_list, counts_list, k: int) -> TopKResult:
     """Merge per-part top-k buffers of *heterogeneous* widths.
 
@@ -62,6 +65,7 @@ def merge_two(
     return _cpq.topk_from_candidates(ids, counts, k)
 
 
+@tracing.scoped(tracing.MERGE)
 def tree_merge(ids: jnp.ndarray, counts: jnp.ndarray, k: int):
     """log2(S) pairwise merge of [S, Q, kp] buffers (ids globalised).
 

@@ -61,6 +61,7 @@ from repro.core.routing import Routing
 from repro.core.select import select_topk
 from repro.core.types import (Engine, SearchParams, SignatureLayout,
                               TopKMethod, TopKResult)
+from repro.runtime import tracing
 
 MatchLike = Union[Engine, str, "_engines.MatchModel",
                   Callable[[jnp.ndarray, Any], jnp.ndarray]]
@@ -537,7 +538,9 @@ def _part_topk(plan: QueryPlan, data: jnp.ndarray, queries: Any, offset,
     [Q, k], empty slots -1."""
     params = plan.params if k is None or k == plan.params.k \
         else dataclasses.replace(plan.params, k=k)
-    counts = _mask_pad_counts(plan.match(data, queries), offset, plan.n_objects)
+    with tracing.scope(tracing.MATCH):
+        counts = plan.match(data, queries)
+    counts = _mask_pad_counts(counts, offset, plan.n_objects)
     local = select_topk(counts, params, use_fused_hist=plan.fused_hist)
     gids = jnp.where(local.ids >= 0, local.ids + offset, -1)
     return _mask_invalid(gids, local.counts, plan.n_objects)
@@ -551,12 +554,14 @@ def _fused_candidates_topk(fused_match, data, queries, k: int):
     ascending global-id ranges, so the buffer as a whole is id-ascending
     within equal counts -- exactly what topk_from_candidates' stable merge
     needs for the global tie-break."""
-    cids, ccnt = fused_match(data, queries, k)
-    if cids.shape[1] < k:  # tiny corpus: fewer candidate slots than k
-        fill = jnp.full((cids.shape[0], k - cids.shape[1]), -1, jnp.int32)
-        cids = jnp.concatenate([cids, fill], axis=1)
-        ccnt = jnp.concatenate([ccnt, fill], axis=1)
-    return _cpq.topk_from_candidates(cids, ccnt, k)
+    with tracing.scope(tracing.MATCH):
+        cids, ccnt = fused_match(data, queries, k)
+    with tracing.scope(tracing.ORDER):
+        if cids.shape[1] < k:  # tiny corpus: fewer candidate slots than k
+            fill = jnp.full((cids.shape[0], k - cids.shape[1]), -1, jnp.int32)
+            cids = jnp.concatenate([cids, fill], axis=1)
+            ccnt = jnp.concatenate([ccnt, fill], axis=1)
+        return _cpq.topk_from_candidates(cids, ccnt, k)
 
 
 def _build_monolithic(plan: QueryPlan, key):
@@ -573,7 +578,9 @@ def _build_monolithic(plan: QueryPlan, key):
 
     def run(data: jnp.ndarray, queries: Any) -> TopKResult:
         _note_trace(key)
-        counts = _mask_pad_counts(plan.match(data, queries), 0, plan.n_objects)
+        with tracing.scope(tracing.MATCH):
+            counts = plan.match(data, queries)
+        counts = _mask_pad_counts(counts, 0, plan.n_objects)
         # selection is the merge: return select_topk's result (threshold
         # included) exactly as the pre-planner single-device search did
         return select_topk(counts, plan.params, use_fused_hist=plan.fused_hist)
@@ -595,9 +602,10 @@ def _build_scan(plan: QueryPlan, key):
             best_ids, best_counts = carry
             part, chunk_idx = xs
             gids, gcnt = _part_topk(plan, part, queries, chunk_idx * nc)
-            ids = jnp.concatenate([best_ids, gids[:, :k]], axis=-1)
-            cnt = jnp.concatenate([best_counts, gcnt[:, :k]], axis=-1)
-            return _cpq.topk_from_candidates(ids, cnt, k), None
+            with tracing.scope(tracing.MERGE):
+                ids = jnp.concatenate([best_ids, gids[:, :k]], axis=-1)
+                cnt = jnp.concatenate([best_counts, gcnt[:, :k]], axis=-1)
+                return _cpq.topk_from_candidates(ids, cnt, k), None
 
         xs = (chunks, jnp.arange(plan.n_parts, dtype=jnp.int32))
         (ids, counts), _ = jax.lax.scan(step, init, xs)
@@ -636,7 +644,8 @@ def _part_fn(plan: QueryPlan, rows: int):
                 ids, cnts = _fused_candidates_topk(fused_match, part,
                                                    queries, params.k)
                 return jnp.where(ids >= 0, ids + offset, -1), cnts
-            counts = match(part, queries)
+            with tracing.scope(tracing.MATCH):
+                counts = match(part, queries)
             if masked:
                 counts = _mask_pad_counts(counts, offset, n_limit)
             local = select_topk(counts, params, use_fused_hist=fused)
@@ -664,9 +673,10 @@ def _scan_host_parts(plan: QueryPlan, parts, queries,
         if int(part.shape[0]) != rows:
             raise ValueError(f"part has {int(part.shape[0])} rows, plan says {rows}")
         if part_mask is None or part_mask[i]:
-            part = jax.device_put(part)
-            gids, gcnt = _part_fn(plan, rows)(part, queries, jnp.int32(offset),
-                                              n_limit)
+            with tracing.span(tracing.PART, part=i, rows=rows):
+                part = jax.device_put(part)
+                gids, gcnt = _part_fn(plan, rows)(part, queries,
+                                                  jnp.int32(offset), n_limit)
             buf_ids.append(gids)
             buf_counts.append(gcnt)
         offset += rows
@@ -674,7 +684,8 @@ def _scan_host_parts(plan: QueryPlan, parts, queries,
         q = jax.tree_util.tree_leaves(queries)[0].shape[0]
         empty = jnp.full((q, plan.params.k), -1, dtype=jnp.int32)
         return TopKResult(ids=empty, counts=empty, threshold=empty[:, -1])
-    return _merge.merge_ragged(buf_ids, buf_counts, plan.params.k)
+    with tracing.span(tracing.MERGE, parts=len(buf_ids)):
+        return _merge.merge_ragged(buf_ids, buf_counts, plan.params.k)
 
 
 def _route(plan: QueryPlan, router: Optional["_routing.Router"],
@@ -696,7 +707,8 @@ def _route(plan: QueryPlan, router: Optional["_routing.Router"],
             f"segments"
         )
     rq = queries if route_queries is None else route_queries
-    return router.select(rq, plan.nprobe)
+    with tracing.span(tracing.ROUTE):
+        return router.select(rq, plan.nprobe)
 
 
 def _skipped_could_contribute(result: TopKResult, ubs: np.ndarray,

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from repro.core import cpq as _cpq
 from repro.core import spq as _spq
 from repro.core.types import SearchParams, TopKMethod, TopKResult
+from repro.runtime import tracing
 
 
 def select_topk(
@@ -37,7 +38,8 @@ def select_topk(
         if hist is None and use_fused_hist:
             from repro.kernels import ops as kops
 
-            hist = kops.cpq_hist(counts, params.max_count)
+            with tracing.scope(tracing.HIST):
+                hist = kops.cpq_hist(counts, params.max_count)
         return _cpq.cpq_select(counts, params, hist=hist)
     if params.method == TopKMethod.SPQ:
         return _spq.spq_select(counts, params)

@@ -62,6 +62,7 @@ from repro.core import plan as plan_lib
 from repro.core import routing as routing_lib
 from repro.core.segments import SegmentedIndex
 from repro.core.types import TopKResult
+from repro.runtime import tracing
 from repro.runtime.fault_tolerance import HeartbeatMonitor
 from repro.serve.metrics import FrontendMetrics
 from repro.serve.retrieval import RetrievalService
@@ -158,6 +159,7 @@ class ServingFrontend:
         self._free_slots = list(range(max_tenants))
         self._reg = threading.Condition()   # tenant registry + pending waits
         self._seq = itertools.count()
+        self._dispatch_seq = itertools.count()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         if start:
@@ -171,6 +173,7 @@ class ServingFrontend:
         call this once their tenants are registered)."""
         if self._stop.is_set():
             raise RuntimeError("frontend is closed; build a new one")
+        tracing.install_gc_spans()
         if self._thread is None or not self._thread.is_alive():
             self._thread = threading.Thread(target=self._loop,
                                             name="serving-frontend",
@@ -300,37 +303,39 @@ class ServingFrontend:
         tenant, empty/missized query batches, draining tenants, queue-full
         `Overloaded`) happens synchronously on the caller's thread.  The
         future carries the request-order id as `.request_seq`."""
-        if self._stop.is_set():
-            raise RuntimeError("frontend is closed: submit rejected")
-        t = self._tenant(tenant, for_submit=True)
-        method = TopKMethod(method)
-        routing = routing_lib.Routing(routing)
-        emb = t.service.resolve_queries(queries, embeddings)
-        key = (tenant, t.service.batch_compat_key(
-            k, method, routing, nprobe=nprobe, candidate_cap=candidate_cap))
-        dispatch_k = int(k) if candidate_cap is not None else plan_lib.k_bucket(k)
-        fut: Future = Future()
-        req = Request(
-            seq=next(self._seq), tenant=tenant, embeddings=emb, k=int(k),
-            dispatch_k=dispatch_k, method=method, routing=routing,
-            nprobe=nprobe, candidate_cap=candidate_cap, key=key, future=fut,
-            submitted_at=time.perf_counter(),
-        )
-        fut.request_seq = req.seq
-        with self._reg:
-            t.pending += 1
-        try:
-            depth = self._queue.offer(req)
-        except Overloaded:
+        with tracing.span(tracing.SUBMIT) as sp:
+            if self._stop.is_set():
+                raise RuntimeError("frontend is closed: submit rejected")
+            t = self._tenant(tenant, for_submit=True)
+            method = TopKMethod(method)
+            routing = routing_lib.Routing(routing)
+            emb = t.service.resolve_queries(queries, embeddings)
+            key = (tenant, t.service.batch_compat_key(
+                k, method, routing, nprobe=nprobe, candidate_cap=candidate_cap))
+            dispatch_k = int(k) if candidate_cap is not None else plan_lib.k_bucket(k)
+            fut: Future = Future()
+            req = Request(
+                seq=next(self._seq), tenant=tenant, embeddings=emb, k=int(k),
+                dispatch_k=dispatch_k, method=method, routing=routing,
+                nprobe=nprobe, candidate_cap=candidate_cap, key=key, future=fut,
+                submitted_at=time.perf_counter(),
+            )
+            fut.request_seq = req.seq
+            sp.set_metadata(request=req.seq)
             with self._reg:
-                t.pending -= 1
-                self._reg.notify_all()
-            self._metrics.record_shed(tenant)
-            raise
-        self._hb.beat(t.slot)
-        self._metrics.record_submit(tenant, req.n_queries)
-        self._metrics.record_queue_depth(depth)
-        return fut
+                t.pending += 1
+            try:
+                depth = self._queue.offer(req)
+            except Overloaded:
+                with self._reg:
+                    t.pending -= 1
+                    self._reg.notify_all()
+                self._metrics.record_shed(tenant)
+                raise
+            self._hb.beat(t.slot)
+            self._metrics.record_submit(tenant, req.n_queries)
+            self._metrics.record_queue_depth(depth)
+            return fut
 
     def search(self, tenant: str, queries=None, k: int = 10, **kw):
         """Synchronous convenience: `submit(...).result()`."""
@@ -363,57 +368,74 @@ class ServingFrontend:
         results back out, resolve futures.  A failure resolves every future
         in the group exceptionally; the loop itself never dies."""
         first = group[0]
-        try:
-            t = self._tenant(first.tenant)
-            stacked = group[0].embeddings if len(group) == 1 else \
-                np.concatenate([np.asarray(r.embeddings) for r in group], axis=0)
-            rows = int(np.shape(stacked)[0])
-            # query-row bucketing: pad the stacked batch to the next power of
-            # two so steady-state serving cycles through O(log max_batch)
-            # compiled shapes instead of tracing a fresh executable per
-            # distinct pile-up size.  Padding rows are copies of row 0 and
-            # are sliced away below -- every engine's match/select/merge is
-            # per-query independent, so real rows are unaffected (the same
-            # argument that makes the k-bucket slice bit-exact).
-            pad = plan_lib.k_bucket(rows) - rows
-            if pad:
-                stacked = np.concatenate(
-                    [stacked, np.repeat(np.asarray(stacked[:1]), pad, axis=0)],
-                    axis=0)
-            with t.lock:
-                res, sims = t.service.search(
-                    None, k=first.dispatch_k, embeddings=stacked,
-                    method=first.method, routing=first.routing,
-                    nprobe=first.nprobe, candidate_cap=first.candidate_cap)
-            ids = np.asarray(res.ids)
-            counts = np.asarray(res.counts)
-            sims_np = None if sims is None else np.asarray(sims)
-            done = time.perf_counter()
-            lo = 0
-            for req in group:
-                hi = lo + req.n_queries
-                rcnt = counts[lo:hi, :req.k]
-                out = TopKResult(ids=ids[lo:hi, :req.k], counts=rcnt,
-                                 threshold=rcnt[:, -1])
-                rsims = None if sims_np is None else sims_np[lo:hi, :req.k]
-                self._metrics.record_completion(req.tenant,
-                                                done - req.submitted_at)
-                req.future.set_result((out, rsims))
-                lo = hi
-            self._metrics.record_dispatch(len(group), lo)
-        # Scatter boundary: whatever a dispatch raises (including
-        # KeyboardInterrupt mid-device-call) must resolve the group's
-        # futures exceptionally -- a dead dispatch loop would hang every
-        # waiting caller forever.
-        # genielint: ignore[broad-except]
-        except BaseException as e:  # noqa: BLE001 -- scatter, don't die
-            for req in group:
-                if not req.future.done():
-                    req.future.set_exception(e)
-        finally:
-            with self._reg:
+        waits_us = [(time.perf_counter() - r.submitted_at) * 1e6 for r in group]
+        self._metrics.record_queue_wait(waits_us)
+        with tracing.span(tracing.DISPATCH, dispatch=next(self._dispatch_seq),
+                          requests=len(group), first_request=first.seq,
+                          queue_wait_us_sum=sum(waits_us),
+                          queue_wait_us_max=max(waits_us)) as sp:
+            cpu0 = time.thread_time_ns()
+            try:
+                t = self._tenant(first.tenant)
+                with tracing.span(tracing.STACK):
+                    stacked = group[0].embeddings if len(group) == 1 else \
+                        np.concatenate([np.asarray(r.embeddings)
+                                        for r in group], axis=0)
+                    rows = int(np.shape(stacked)[0])
+                    # query-row bucketing: pad the stacked batch to the next
+                    # power of two so steady-state serving cycles through
+                    # O(log max_batch) compiled shapes instead of tracing a
+                    # fresh executable per distinct pile-up size.  Padding
+                    # rows are copies of row 0 and are sliced away below --
+                    # every engine's match/select/merge is per-query
+                    # independent, so real rows are unaffected (the same
+                    # argument that makes the k-bucket slice bit-exact).
+                    pad = plan_lib.k_bucket(rows) - rows
+                    if pad:
+                        stacked = np.concatenate(
+                            [stacked, np.repeat(np.asarray(stacked[:1]), pad,
+                                                axis=0)], axis=0)
+                sp.set_metadata(rows=rows, padded_rows=rows + pad)
+                with t.lock, tracing.span(tracing.SEARCH):
+                    res, sims = t.service.search(
+                        None, k=first.dispatch_k, embeddings=stacked,
+                        method=first.method, routing=first.routing,
+                        nprobe=first.nprobe, candidate_cap=first.candidate_cap)
+                with tracing.span(tracing.WAIT):
+                    ids = np.asarray(res.ids)
+                    counts = np.asarray(res.counts)
+                    sims_np = None if sims is None else np.asarray(sims)
+                done = time.perf_counter()
+                lo = 0
+                with tracing.span(tracing.SCATTER):
+                    for req in group:
+                        hi = lo + req.n_queries
+                        rcnt = counts[lo:hi, :req.k]
+                        out = TopKResult(ids=ids[lo:hi, :req.k], counts=rcnt,
+                                         threshold=rcnt[:, -1])
+                        rsims = None if sims_np is None else sims_np[lo:hi, :req.k]
+                        self._metrics.record_completion(req.tenant,
+                                                        done - req.submitted_at)
+                        req.future.set_result((out, rsims))
+                        lo = hi
+                self._metrics.record_dispatch(len(group), lo, rows + pad)
+            # Scatter boundary: whatever a dispatch raises (including
+            # KeyboardInterrupt mid-device-call) must resolve the group's
+            # futures exceptionally -- a dead dispatch loop would hang every
+            # waiting caller forever.
+            # genielint: ignore[broad-except]
+            except BaseException as e:  # noqa: BLE001 -- scatter, don't die
                 for req in group:
-                    tt = self._tenants.get(req.tenant)
-                    if tt is not None:
-                        tt.pending -= 1
-                self._reg.notify_all()
+                    if not req.future.done():
+                        req.future.set_exception(e)
+            finally:
+                with self._reg:
+                    for req in group:
+                        tt = self._tenants.get(req.tenant)
+                        if tt is not None:
+                            tt.pending -= 1
+                    self._reg.notify_all()
+                # this thread's CPU time: its own work, not its waits on the
+                # device (blocking reads, launches queued behind programs)
+                sp.set_metadata(
+                    host_cpu_us=(time.thread_time_ns() - cpu0) * 1e-3)

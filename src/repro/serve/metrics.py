@@ -1,5 +1,5 @@
 """Serving-front-end metrics: per-tenant latency percentiles, batch
-occupancy, coalesce ratio, queue depth.
+occupancy, coalesce ratio, queue depth and queue wait, padded rows.
 
 The front-end (serve/frontend.py) is judged on exactly the numbers Johnson
 et al.'s billion-scale serving work tracks -- tail latency and device
@@ -67,6 +67,9 @@ class FrontendMetrics:
         self._dispatched_requests = 0        # requests served by them
         self._dispatched_queries = 0         # query rows served by them
         self._occupancy = collections.deque(maxlen=self.window)  # queries/dispatch
+        self._padded_rows = 0                # rows dispatched, padding included
+        self._queue_wait_us = 0.0            # submit -> dispatch start, summed
+        self._queue_waits = 0                # requests in that sum
         self._queue_depth = 0
         self._queue_high_water = 0
 
@@ -93,13 +96,24 @@ class FrontendMetrics:
             self._queue_depth = int(depth)
             self._queue_high_water = max(self._queue_high_water, int(depth))
 
-    def record_dispatch(self, n_requests: int, n_queries: int) -> None:
+    def record_queue_wait(self, waits_us) -> None:
+        """Queue waits (microseconds, submit to the start of the dispatch
+        that takes the request) of one dispatch's requests."""
+        with self._lock:
+            self._queue_wait_us += float(sum(waits_us))
+            self._queue_waits += len(waits_us)
+
+    def record_dispatch(self, n_requests: int, n_queries: int,
+                        padded_rows: Optional[int] = None) -> None:
         """One coalesced device dispatch serving `n_requests` requests whose
-        stacked query batch held `n_queries` rows."""
+        stacked query batch held `n_queries` rows, padded to `padded_rows`
+        (default: no padding)."""
         with self._lock:
             self._dispatches += 1
             self._dispatched_requests += int(n_requests)
             self._dispatched_queries += int(n_queries)
+            self._padded_rows += int(n_queries if padded_rows is None
+                                     else padded_rows)
             self._occupancy.append(int(n_queries))
 
     def record_completion(self, tenant: str, latency_s: float) -> None:
@@ -146,6 +160,16 @@ class FrontendMetrics:
                 if self._dispatches else 0.0,
                 # mean stacked-query rows per dispatch over the window
                 batch_occupancy=round(sum(occ) / len(occ), 3) if occ else 0.0,
+                # rows dispatched with the power-of-two padding, and the
+                # share of them that are real query rows
+                padded_rows=self._padded_rows,
+                row_occupancy=round(
+                    self._dispatched_queries / self._padded_rows, 3)
+                if self._padded_rows else 0.0,
+                # mean submit -> dispatch-start wait of dispatched requests
+                queue_wait_ms_mean=round(
+                    self._queue_wait_us / self._queue_waits * 1e-3, 3)
+                if self._queue_waits else 0.0,
                 queue_depth=self._queue_depth,
                 queue_high_water=self._queue_high_water,
                 p50_ms=round(percentile(all_lat, 50) * 1e3, 3),

@@ -47,6 +47,7 @@ from repro.core import plan as plan_lib
 from repro.core import routing as routing_lib
 from repro.core.lsh import tau_ann
 from repro.core.types import SignatureLayout
+from repro.runtime import tracing
 
 
 @dataclasses.dataclass
@@ -260,8 +261,10 @@ class RetrievalService:
                 "call add() before search()"
             )
         routing = routing_lib.Routing(routing)
-        emb = self.resolve_queries(queries, embeddings)
-        qsigs = self._hash(emb)
+        with tracing.span(tracing.HASH) as sp:
+            emb = self.resolve_queries(queries, embeddings)
+            sp.set_metadata(rows=int(emb.shape[0]))
+            qsigs = self._hash(emb)
         if self.mesh is None:
             # the cached per-tenant router (fingerprint-keyed) rides into the
             # segment search, so interleaved add/search only rebuild routing
@@ -302,7 +305,9 @@ class RetrievalService:
                                    router=router, route_queries=q_wide)
         # scheme-paired MLE: c/m for bucketed families (Eqn 7), the simhash
         # angle inversion for COSINE
-        sims = self._scheme.mle(np.asarray(res.counts), self.m)
+        with tracing.span(tracing.WAIT):
+            counts = np.asarray(res.counts)
+        sims = self._scheme.mle(counts, self.m)
         return res, sims
 
     def tune(self, queries, k: int = 10, *,
@@ -329,8 +334,10 @@ class RetrievalService:
                 "call add() before tune()"
             )
         routing = routing_lib.Routing(routing)
-        emb = self.resolve_queries(queries, embeddings)
-        qsigs = self._hash(emb)
+        with tracing.span(tracing.HASH) as sp:
+            emb = self.resolve_queries(queries, embeddings)
+            sp.set_metadata(rows=int(emb.shape[0]))
+            qsigs = self._hash(emb)
         model = engines_lib.get(self._scheme.engine)
         q_wide = model.prepare_queries(qsigs)
         q_exec = q_wide

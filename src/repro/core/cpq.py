@@ -13,15 +13,21 @@ without any atomics:
 
   phase 1 (histogram):  hist[q, t] = #(counts[q, n] == t)   (Pallas kernel)
   phase 2 (gate):       AT = min(t >= 1 : ZA[t] < k);  threshold = AT - 1
-  phase 3 (hash table): masked two-class compaction (strict > threshold first,
-                        then ties == threshold) into a fixed buffer of size cap
-                        -- the Hash-Table analogue; a single scan, no sort of N.
+  phase 3 (hash table): two-class compaction (strict > threshold first, then
+                        ties == threshold, each in id order) into a fixed
+                        buffer of size cap -- the Hash-Table analogue.  Each
+                        of the cap slots gathers its own object by a binary
+                        search on one prefix sum over the strict mask then
+                        the tie mask: O(N) prefix sums and O(cap * log N)
+                        gathers; no sort of N and no scatter over N.
 
 Only the final cap-sized buffer (cap ~ 2k << N) is ordered, reproducing the
 paper's "scan the small HT once" property.  Exactness versus a full sort is
 property-tested in tests/test_cpq.py.
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -77,29 +83,36 @@ def audit_threshold(hist: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray
 def _compact_candidates(
     counts: jnp.ndarray, threshold: jnp.ndarray, cap: int
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """Two-class masked compaction into a cap-sized buffer per query.
+    """Two-class compaction into a cap-sized buffer per query.
 
-    Objects with count > threshold ("strict", provably < k of them by the Gate)
-    are written first; ties (== threshold) fill the remaining slots in id order
-    (the paper breaks ties randomly).  Returns (ids [Q, cap], vals [Q, cap]),
-    empty slots marked id=-1, val=-1.
+    Row q of the output holds the objects with count > threshold ("strict",
+    provably < k of them by the Gate) in id order, then the ties
+    (== threshold) in id order (the paper breaks ties randomly), cut at cap.
+    Returns (ids [Q, cap], vals [Q, cap]), empty slots marked id=-1, val=-1.
+
+    The buffer is filled by gathering, not by scattering the N objects.
+    One prefix sum runs over the strict mask followed by the tie mask, a
+    monotone row of length 2N whose first half counts the strict objects
+    up to each id and whose second half continues with the ties.  Slot j
+    holds the object at the first position where it reaches j + 1: a
+    strict object at that id in the first half, a tie at (position - N)
+    in the second.  A binary search finds it: O(N) prefix sums and
+    O(cap * log N) gathers per row.  A scatter over N would be serialised
+    per element on the TPU, though nearly every object lands in no slot.
     """
     q, n = counts.shape
     c = counts.astype(jnp.int32)
     thr = threshold[:, None]
-    strict = c > thr
-    tie = c == thr
-    n_strict = jnp.sum(strict.astype(jnp.int32), axis=-1, keepdims=True)
-    pos_strict = jnp.cumsum(strict.astype(jnp.int32), axis=-1) - 1
-    pos_tie = n_strict + jnp.cumsum(tie.astype(jnp.int32), axis=-1) - 1
-    pos = jnp.where(strict, pos_strict, jnp.where(tie, pos_tie, cap))
-    pos = jnp.minimum(pos, cap)                  # cap slot == drop
-    ids = jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32)[None, :], (q, n))
-    out_ids = jnp.full((q, cap + 1), -1, dtype=jnp.int32)
-    out_vals = jnp.full((q, cap + 1), -1, dtype=jnp.int32)
-    out_ids = jax.vmap(lambda o, p, v: o.at[p].set(v, mode="drop"))(out_ids, pos, ids)
-    out_vals = jax.vmap(lambda o, p, v: o.at[p].set(v, mode="drop"))(out_vals, pos, c)
-    return out_ids[:, :cap], out_vals[:, :cap]
+    both = jnp.concatenate([c > thr, c == thr], axis=-1)   # [Q, 2N]
+    rank = jnp.cumsum(both.astype(jnp.int32), axis=-1)
+    slot = jnp.broadcast_to(jnp.arange(cap, dtype=jnp.int32), (q, cap))
+    first = jax.vmap(functools.partial(jnp.searchsorted, side="left", method="scan"))
+    pos = first(rank, slot + 1)                          # == 2n past the last
+    idx = jnp.minimum(jnp.where(pos < n, pos, pos - n), n - 1).astype(jnp.int32)
+    filled = slot < rank[:, -1:]
+    out_ids = jnp.where(filled, idx, -1)
+    out_vals = jnp.where(filled, jnp.take_along_axis(c, idx, axis=-1), -1)
+    return out_ids, out_vals
 
 
 def topk_from_candidates(ids: jnp.ndarray, vals: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:

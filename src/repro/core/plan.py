@@ -554,7 +554,7 @@ def _fused_candidates_topk(fused_match, data, queries, k: int):
     ascending global-id ranges, so the buffer as a whole is id-ascending
     within equal counts -- exactly what topk_from_candidates' stable merge
     needs for the global tie-break."""
-    with tracing.scope(tracing.MATCH):
+    with tracing.scope(tracing.TOPK):
         cids, ccnt = fused_match(data, queries, k)
     with tracing.scope(tracing.ORDER):
         if cids.shape[1] < k:  # tiny corpus: fewer candidate slots than k
@@ -562,6 +562,24 @@ def _fused_candidates_topk(fused_match, data, queries, k: int):
             cids = jnp.concatenate([cids, fill], axis=1)
             ccnt = jnp.concatenate([ccnt, fill], axis=1)
         return _cpq.topk_from_candidates(cids, ccnt, k)
+
+
+def _candidate_slots(plan: QueryPlan, part, queries) -> dict:
+    """The `candidates` stat of a host-loop part span: the slots per query
+    row a fused plan's kernel writes for the part (n_tiles * min(k, tile_n),
+    whatever the number of query rows), {} for a plan without one."""
+    if plan.fused_match is None:
+        return {}
+    k = plan.part_k(int(part.shape[0]))
+    key = ("slots", plan.fused_match, tuple(part.shape), str(part.dtype), k)
+
+    def build():
+        spec = jax.ShapeDtypeStruct(part.shape, part.dtype)
+        cids, _ = jax.eval_shape(lambda d, q: plan.fused_match(d, q, k),
+                                 spec, queries)
+        return int(cids.shape[1])
+
+    return {"candidates": _cached(key, build)}
 
 
 def _build_monolithic(plan: QueryPlan, key):
@@ -673,7 +691,8 @@ def _scan_host_parts(plan: QueryPlan, parts, queries,
         if int(part.shape[0]) != rows:
             raise ValueError(f"part has {int(part.shape[0])} rows, plan says {rows}")
         if part_mask is None or part_mask[i]:
-            with tracing.span(tracing.PART, part=i, rows=rows):
+            with tracing.span(tracing.PART, part=i, rows=rows,
+                              **_candidate_slots(plan, part, queries)):
                 part = jax.device_put(part)
                 gids, gcnt = _part_fn(plan, rows)(part, queries,
                                                   jnp.int32(offset), n_limit)

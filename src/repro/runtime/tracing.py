@@ -41,6 +41,7 @@ HASH = "genie.hash"          # LSH hashing of the query rows
 MERGE = "genie.merge"        # merging per-part candidate buffers
 # device scopes
 MATCH = "genie.match"        # the match kernel (counts [Q, N])
+TOPK = "genie.topk"          # a fused match -> count -> local top-k kernel
 HIST = "genie.hist"          # the c-PQ count histogram
 GATE = "genie.gate"          # the audit threshold from the histogram
 COMPACT = "genie.compact"    # the c-PQ candidate compaction

@@ -187,3 +187,53 @@ def test_build_stats_report_signature_footprint():
     tstats = engines.get(Engine.TANIMOTO).build_stats(jnp.asarray(sk))
     assert tstats.bytes_signatures_wide == 64 * 20 * 4
     assert tstats.bytes_signatures_packed == 64 * 20        # uint8 buckets
+
+
+# ---------------------------------------------------------------------------
+# The fused plans' candidate reduction: ties at the k-th count across parts
+# ---------------------------------------------------------------------------
+
+def _tied_corpus(engine, rng):
+    """(data, queries): 6 distinct rows tiled 50 times, so every count is
+    shared by 50 rows, straddling the segments below."""
+    if engine is Engine.COSINE:
+        base = rng.standard_normal((6, 40)).astype(np.float32)
+        q = rng.standard_normal((3, 40)).astype(np.float32)
+    else:
+        base = rng.integers(0, 4, (6, 40)).astype(np.int32)
+        q = rng.integers(0, 4, (3, 40)).astype(np.int32)
+    return np.tile(base, (50, 1)), q
+
+
+@pytest.mark.parametrize("engine", [Engine.COSINE, Engine.TANIMOTO])
+def test_fused_reduction_equals_wide_with_ties_at_the_kth_count(engine):
+    """With rows forced to tie at the k-th count across three segments, and
+    with a corpus smaller than k, the fused PACKED plans (per-tile buffers
+    sorted by core/plan.py `_fused_candidates_topk`) give the WIDE count
+    path's result bit for bit: the lowest ids of the tied count."""
+    from repro.core import GenieIndex, SegmentedIndex
+    from repro.core import plan as plan_lib
+
+    data, q = _tied_corpus(engine, np.random.default_rng(7))
+    got = {}
+    for layout in ("wide", "packed"):
+        idx = SegmentedIndex(engine=engine, signature_layout=layout)
+        for lo in (0, 100, 200):
+            idx.add(data[lo:lo + 100])
+        res = idx.search(q, k=7)
+        tiny = GenieIndex.build(engine, data[:3], signature_layout=layout
+                                ).search(q, k=8)
+        got[layout] = [np.asarray(a) for a in (res.ids, res.counts,
+                                               tiny.ids, tiny.counts)]
+        plan = plan_lib.plan_search(engine, 7, idx.max_count,
+                                    layout=plan_lib.Layout.SEGMENTED,
+                                    part_rows=(100,) * 3,
+                                    signature_layout=layout)
+        assert (plan.fused_match is not None) == (layout == "packed")
+    for w, p in zip(got["wide"], got["packed"]):
+        assert np.array_equal(w, p)
+    ids, counts = got["packed"][:2]
+    # the 7 best are ties of one count: the lowest ids of that count's rows
+    assert np.all(counts == counts[:, :1])
+    assert np.all(np.diff(ids, axis=1) == 6)
+    assert np.all(got["packed"][2][:, 3:] == -1)

@@ -136,3 +136,28 @@ def test_sift_segment_program_compiles_and_fits(one_chip, monkeypatch):
     used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
             + mem.output_size_in_bytes)
     assert corpus + used < HBM_BYTES, (corpus, used)
+
+
+def test_deep96_segment_program_compiles_and_fits(one_chip, monkeypatch):
+    """The served per-segment program of the deep-image-96-angular
+    deployment (9.99M SimHash rows, 237 bits packed into 8 words, 16 equal
+    segments) at a 128-row dispatch, k = 10 bucketed to 16: the fused match
+    -> count -> local top-k kernel, then the sort of its candidate buffer,
+    fits beside the resident packed corpus."""
+    monkeypatch.setattr(common, "use_interpret",
+                        lambda interpret: False if interpret is None else interpret)
+    rows = 624_375
+    plan = plan_lib.plan_search(Engine.COSINE, 16, M,
+                                layout=plan_lib.Layout.SEGMENTED,
+                                part_rows=(rows,) * 16, signature_layout="packed")
+    assert plan.fused_match is not None
+    fn = plan_lib._part_fn(plan, rows)
+    compiled = fn.lower(_spec(one_chip, (rows, 8)), _spec(one_chip, (128, 8)),
+                        _spec(one_chip, ()), _spec(one_chip, ())).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 1 and "packed_cosine_topk" in text
+    mem = compiled.memory_analysis()
+    corpus = 16 * rows * 8 * 4
+    used = (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + mem.output_size_in_bytes)
+    assert corpus + used < HBM_BYTES, (corpus, used)

@@ -126,6 +126,21 @@ def test_part_program_hlo_carries_the_scopes():
         assert f"/{scope}/" in text, scope
 
 
+def test_fused_part_program_hlo_carries_the_topk_scope():
+    """A PACKED plan's part program runs the fused match -> count -> local
+    top-k kernel in `genie.topk` and sorts its candidates in `genie.order`;
+    no count matrix, so no `genie.match`."""
+    plan = plan_lib.plan_search(Engine.COSINE, 8, 64,
+                                layout=plan_lib.Layout.SEGMENTED,
+                                part_rows=(512,), signature_layout="packed")
+    fn = plan_lib._part_fn(plan, 512)
+    spec = lambda shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    text = fn.lower(spec((512, 2)), spec((4, 2)), spec(()), spec(())
+                    ).compile().as_text()
+    assert f"/{tracing.TOPK}/" in text and f"/{tracing.ORDER}/" in text
+    assert f"/{tracing.MATCH}/" not in text
+
+
 def test_hash_scope_reaches_a_jitted_caller():
     from repro.core import lsh
 

@@ -1,8 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels.
 
 Each wrapper pads inputs to tile multiples (with values that cannot produce
-spurious matches), lays them out the way the kernel reads them (the VPU
-count kernels take grouped queries and transposed data, see
+spurious matches; cpq_hist masks its ragged edge tiles in the kernel
+instead), lays them out the way the kernel reads them (the VPU count
+kernels take grouped queries and transposed data, see
 common.column_sweep), dispatches to the kernel (compiled on the TPU,
 interpreted on the CPU backend), and slices the result back to logical
 shape.  These are the functions the GENIE engines call; repro.kernels.ref
@@ -336,13 +337,18 @@ def cpq_hist(
     tile_n: int | None = None,
     interpret: bool | None = None,
 ) -> jnp.ndarray:
-    """c-PQ Gate histogram: int32 [Q, max_count + 1]."""
+    """c-PQ Gate histogram: int32 [Q, max_count + 1].
+
+    The kernel counts over its digit grid of D * D bins (D from max_count,
+    kernels/cpq_hist.py), lane-padded to a multiple of 128; the bins past
+    max_count are sliced off here.  The count matrix is passed as it is:
+    ragged edge tiles are masked in the kernel."""
     qn, nn = counts.shape
-    tq = common.pick_tile(qn, tile_q or _cpq_hist.TILE_Q, 8, knob="tile_q")
+    base = _cpq_hist.digit_base(max_count)
+    tq = common.pick_tile(qn, tile_q or _cpq_hist.query_tile(base), 8, knob="tile_q")
     tn = common.pick_tile(nn, tile_n or _cpq_hist.TILE_N, 128, knob="tile_n")
-    nbins = common.ceil_to(max_count + 1, 128)
-    c = common.pad_to(common.pad_to(counts.astype(jnp.int32), tq, 0, -1), tn, 1, -1)
     out = _cpq_hist.cpq_hist_pallas(
-        c, nbins, tile_q=tq, tile_n=tn, interpret=common.use_interpret(interpret)
+        counts.astype(jnp.int32), max_count, tile_q=tq, tile_n=tn,
+        interpret=common.use_interpret(interpret)
     )
-    return out[:qn, : max_count + 1]
+    return out[:, : max_count + 1]

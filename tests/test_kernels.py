@@ -2,6 +2,7 @@
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ops, ref
 
@@ -78,13 +79,50 @@ def test_cosine_count_sweep(q, n, v, rng):
     assert got.min() >= 0 and got.max() <= v
 
 
-@pytest.mark.parametrize("q,n,mx", [(2, 100, 9), (4, 513, 31), (8, 64, 127)])
-def test_cpq_hist_sweep(q, n, mx, rng):
+SMALL_TILES = dict(tile_q=8, tile_n=128)
+# The TPU interpreter, whose reads past an array's edge return 0, a real
+# bin, as stale VMEM may on the chip (plain interpret mode returns INT_MIN,
+# which no mask is needed to reject).
+TPU_SIM = pltpu.InterpretParams(uninitialized_memory="zero")
+
+
+@pytest.mark.parametrize("q,n,mx,tiles,pads", [
+    (2, 100, 9, SMALL_TILES, 0),
+    (4, 513, 31, SMALL_TILES, 0),
+    (8, 64, 127, SMALL_TILES, 0),
+    (16, 5000, 14, {}, 0),        # Adult's domain: D = 4, TQ = 16 of 32
+    (16, 5000, 237, {}, 0),       # SIFT's domain: D = 16, TQ = 8
+    (3, 700, 20, SMALL_TILES, 0),  # D = 8: bins 21..63 exist and read zero
+    (40, 40_000, 14, {}, 0),      # Q % TQ (32) and N % TILE_N (16384) ragged
+    (5, 3000, 237, {}, 300),      # -1 pad columns, as _mask_pad_counts sets
+    (9, 1000, 63, SMALL_TILES, 50),
+])
+def test_cpq_hist_sweep(q, n, mx, tiles, pads, rng):
     counts = rng.integers(0, mx + 1, size=(q, n)).astype(np.int32)
-    got = np.asarray(ops.cpq_hist(jnp.asarray(counts), mx, tile_q=8, tile_n=128))
+    if pads:
+        counts[:, n - pads:] = -1
+        counts[rng.random((q, n)) < 0.05] = -1
+    got = np.asarray(ops.cpq_hist(jnp.asarray(counts), mx, interpret=TPU_SIM,
+                                  **tiles))
     want = np.asarray(ref.cpq_hist(jnp.asarray(counts), mx + 1))
     assert np.array_equal(got, want)
-    assert got.sum(axis=1).max() <= n
+    assert got.sum(axis=1).max() <= n - pads
+
+
+def test_cpq_hist_one_bin_holds_every_count():
+    """A row of 70,001 equal counts fills one bin past 256 within a step
+    and past 2**16 across steps: exact only if nothing accumulates in
+    bfloat16 or int16."""
+    n = 70_001
+    counts = np.zeros((3, n), np.int32)
+    counts[0] = 237
+    counts[1] = 200
+    counts[2, : n // 2] = 17
+    got = np.asarray(ops.cpq_hist(jnp.asarray(counts), 237, interpret=TPU_SIM))
+    want = np.zeros((3, 238), np.int32)
+    want[0, 237] = want[1, 200] = n
+    want[2, 17], want[2, 0] = n // 2, n - n // 2
+    assert np.array_equal(got, want)
 
 
 def test_kernel_vs_engine_end_to_end(rng):

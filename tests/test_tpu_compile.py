@@ -61,6 +61,10 @@ KERNELS = {
     "cpq_hist": (
         lambda c: ops.cpq_hist(c, M, interpret=False),
         [((Q, SEG_ROWS), jnp.int32)]),
+    # Adult's one segment at a 128-row dispatch, counts in 0..14: D = 4
+    "cpq_hist_adult": (
+        lambda c: ops.cpq_hist(c, 14, interpret=False),
+        [((128, 980_000), jnp.int32)]),
     "packed_cosine_count": (
         lambda d, q: ops.packed_cosine_count(d, q, interpret=False),
         [((SEG_ROWS, 8), jnp.int32), ((Q, 8), jnp.int32)]),

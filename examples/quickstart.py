@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import Engine, GenieIndex, SegmentedIndex, TopKMethod, engines
+from repro.core.segments import even_segments
 from repro.core import lsh as lsh_lib
 from repro.core.lsh import tau_ann
 from repro.data.pipeline import synthetic_points
@@ -51,11 +52,18 @@ def main():
     sims = tau_ann.mle_similarity(np.asarray(res.counts[:1]), m)
     print(f"similarity estimates (Eqn 7) for query 0: {np.round(sims, 3)}")
 
-    # 5. the same index streamed as 4 parts (paper section III-D) -- identical
-    #    counts, any registered engine
-    parts = index.search_multiload(qsigs, k=10, n_parts=4)
-    same = bool(np.array_equal(np.asarray(res.counts), np.asarray(parts.counts)))
-    print(f"multiload(4 parts) counts identical: {same}")
+    # 5. multiple loading (paper section III-D): the same signatures sealed
+    #    as 4 even parts held in host memory, streamed through the device one
+    #    part per step -- identical counts, any registered engine
+    parts = SegmentedIndex(engine=Engine.EQ, max_count=index.max_count,
+                           use_kernel=False, host_resident=True)
+    off = 0
+    for rows in even_segments(index.stats.n_objects, 4):
+        parts.add(sigs[off:off + rows])
+        off += rows
+    pres = parts.search(qsigs, k=10)
+    same = bool(np.array_equal(np.asarray(res.counts), np.asarray(pres.counts)))
+    print(f"multiload (4 streamed parts) counts identical: {same}")
 
     # 6. the same machinery, different measures: sign-quantized cosine
     #    (simhash bits -> COSINE sign agreements on the MXU) and Jaccard

@@ -5,8 +5,8 @@
 # search path builds a QueryPlan and delegates to the one executor that calls
 # match kernels, pad masks, select_topk, and the merge buffers.
 from repro.core import (  # noqa: F401
-    cpq, distributed, engines, index, match, merge, multiload, plan, postings,
-    routing, segments, select, spq,
+    cpq, distributed, engines, index, match, merge, plan, postings, routing,
+    segments, select, spq,
 )
 from repro.core.engines import MatchModel  # noqa: F401
 from repro.core.index import GenieIndex  # noqa: F401

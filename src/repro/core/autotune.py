@@ -1,13 +1,12 @@
-"""Hardware-aware plan autotuner: measured cost for tile/layout/routing knobs.
+"""Hardware-aware plan autotuner: measured cost for tile/fusion/routing knobs.
 
 GENIE's pipeline runs at the hardware roofline only when its discrete knobs
 match the machine (PAPER.md section 6): kernel tile sizes (the tile_q /
 tile_n / tile_v / tile_m kwargs kernels/ops.py accepts but nothing drove),
-fused vs. unfused packed match, SEGMENTED vs. MULTILOAD-host part layout,
-the per-part `candidate_cap`, and the routing probe width `nprobe`.  The
-right numbers differ per backend, engine, and corpus shape -- Faiss makes
-the same point for GPU similarity search (PAPERS.md) -- so this module
-closes the loop by *measuring*:
+fused vs. unfused packed match, the per-part `candidate_cap`, and the
+routing probe width `nprobe`.  The right numbers differ per backend, engine,
+and corpus shape -- Faiss makes the same point for GPU similarity search
+(PAPERS.md) -- so this module closes the loop by *measuring*:
 
   * `tune()` greedily walks the knob space one axis at a time, timing real
     executions of real plans through `core.plan.execute` with
@@ -107,10 +106,8 @@ def shape_bucket(n: int) -> int:
 class TunedEntry:
     """One measured winner: the knob set for (engine, layout, shape bucket).
 
-    `layout` is the tuned part-structure choice ("segmented" /
-    "multiload_host"; None = caller's layout stands).  `fused_match` False
-    suppresses the fused packed kernel even where gating allows it; None
-    leaves the default gating alone.  `speedup` is default_us/measured_us
+    `fused_match` False suppresses the fused packed kernel even where
+    gating allows it; None leaves the default gating alone.  `speedup` is default_us/measured_us
     from the final head-to-head -- 1.0 entries record "defaults already
     win here", which stops re-tuning from re-measuring a settled bucket.
     """
@@ -121,7 +118,6 @@ class TunedEntry:
     w_bucket: int
     tile_overrides: tuple = ()
     fused_match: Optional[bool] = None
-    layout: Optional[str] = None
     candidate_cap: Optional[int] = None
     nprobe: Optional[int] = None
     measured_us: float = 0.0
@@ -375,7 +371,9 @@ def price_plan(plan: "_plan.QueryPlan", data, queries, *,
     mode="lower": lower+compile the single-program executable and read the
     XLA cost model (flops / bytes accessed) without executing -- the
     lower-and-cost loop folded in from the old benchmarks/hillclimb.py.
-    Host-loop layouts have no single lowerable program and reject "lower".
+    Only MONOLITHIC plans are one lowerable program: SEGMENTED plans are
+    host-orchestrated and DISTRIBUTED ones need a mesh, so both reject
+    "lower".
     """
     if mode == "measure":
         return {
@@ -386,12 +384,11 @@ def price_plan(plan: "_plan.QueryPlan", data, queries, *,
         }
     if mode != "lower":
         raise ValueError(f"mode must be 'measure' or 'lower', got {mode!r}")
-    if plan.layout not in (_plan.Layout.MONOLITHIC, _plan.Layout.MULTILOAD) \
-            or plan.host_loop:
+    if plan.layout is not _plan.Layout.MONOLITHIC:
         raise ValueError(
             f"mode='lower' needs a single lowerable program; a "
-            f"{plan.layout.value}{' host-loop' if plan.host_loop else ''} "
-            f"plan is host-orchestrated -- price it with mode='measure'"
+            f"{plan.layout.value} plan is not one -- price it with "
+            f"mode='measure'"
         )
     fn = _plan.executable(plan)
     lowered = fn.lower(data, queries)
@@ -519,10 +516,8 @@ def tune(engine: Engine | str | _engines.MatchModel, data, queries, k: int,
     stored-layout pytree -- the serving path, whose sealed segments cannot
     be un-packed; it requires an explicit `max_count` and, for routed
     PACKED tuning, `route_queries` (the canonical WIDE pytree the router
-    scores).  With `part_rows` the search runs part-structured and adds the
-    layout axis (SEGMENTED vs MULTILOAD host loop -- both stream the same
-    per-part arrays, so the choice is purely a merge-orchestration
-    measurement) and, given `router` + `routing`, the nprobe axis.
+    scores).  With `part_rows` the search runs SEGMENTED and, given
+    `router` + `routing`, adds the nprobe axis.
     `budget` caps measured candidates; the default-knob plan is always
     measured first as the baseline, and the returned entry falls back to
     default knobs whenever no candidate beats it (tuned can never regress).
@@ -555,6 +550,7 @@ def tune(engine: Engine | str | _engines.MatchModel, data, queries, k: int,
 
     part_rows = tuple(int(r) for r in part_rows) if part_rows else None
     base_layout = _plan.Layout.SEGMENTED if part_rows else _plan.Layout.MONOLITHIC
+    plan_routing = routing if part_rows else Routing.NONE
     exec_data = _split_parts(stored, part_rows) if part_rows else stored
 
     knobs = model.tile_knobs(True, sig_layout)
@@ -564,21 +560,15 @@ def tune(engine: Engine | str | _engines.MatchModel, data, queries, k: int,
     sweep = _is_sweep(model.engine, sig_layout)
     query_parts = 2 if model.engine is Engine.RANGE else 1   # lo and hi
 
-    state = {
-        "tiles": {}, "fused": None, "candidate_cap": None,
-        "layout": base_layout, "host_loop": False, "nprobe": None,
-    }
+    state = {"tiles": {}, "fused": None, "candidate_cap": None, "nprobe": None}
 
     def make_plan(st):
         p = _plan.plan_search(
             model, k, mc,
-            layout=st["layout"], part_rows=part_rows,
+            layout=base_layout, part_rows=part_rows,
             method=method, candidate_cap=st["candidate_cap"],
-            use_kernel=True, host_loop=st["host_loop"],
-            signature_layout=sig_layout,
-            routing=routing if st["layout"] is not _plan.Layout.MONOLITHIC
-            else Routing.NONE,
-            nprobe=st["nprobe"],
+            use_kernel=True, signature_layout=sig_layout,
+            routing=plan_routing, nprobe=st["nprobe"],
             tile_overrides=st["tiles"] or None,
         )
         if st["fused"] is False and p.fused_match is not None:
@@ -633,13 +623,7 @@ def tune(engine: Engine | str | _engines.MatchModel, data, queries, k: int,
         try_state(dict(best, tiles=dict(best["tiles"]),
                        candidate_cap=None if cap is None else int(cap)))
 
-    # axis 4: part layout -- SEGMENTED vs MULTILOAD host loop stream the
-    # same per-part arrays; only the merge orchestration differs
-    if part_rows:
-        try_state(dict(best, tiles=dict(best["tiles"]),
-                       layout=_plan.Layout.MULTILOAD, host_loop=True))
-
-    # axis 5: routing probe width
+    # axis 4: routing probe width
     if part_rows and router is not None and routing is not Routing.NONE:
         for cand in (1, 2, 4, 8, 16):
             if cand > len(part_rows):
@@ -650,7 +634,7 @@ def tune(engine: Engine | str | _engines.MatchModel, data, queries, k: int,
     # timing on a warming machine favours whoever runs last); keep defaults
     # unless the winner still wins
     default_state = {"tiles": {}, "fused": None, "candidate_cap": None,
-                     "layout": base_layout, "host_loop": False, "nprobe": None}
+                     "nprobe": None}
     if best != default_state:
         default_us, best_us = compare_plans(
             make_plan(default_state), make_plan(best), exec_data, q_stored,
@@ -660,11 +644,6 @@ def tune(engine: Engine | str | _engines.MatchModel, data, queries, k: int,
         best = default_state
         best_us = default_us
 
-    tuned_layout = None
-    if part_rows:
-        tuned_layout = ("multiload_host"
-                        if best["layout"] is _plan.Layout.MULTILOAD
-                        else "segmented")
     entry = TunedEntry(
         engine=model.engine.value,
         signature_layout=sig_layout.value,
@@ -672,7 +651,6 @@ def tune(engine: Engine | str | _engines.MatchModel, data, queries, k: int,
         w_bucket=shape_bucket(width),
         tile_overrides=_engines.canonical_tile_overrides(best["tiles"]),
         fused_match=best["fused"],
-        layout=tuned_layout,
         candidate_cap=best["candidate_cap"],
         nprobe=best["nprobe"],
         measured_us=best_us,

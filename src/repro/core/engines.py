@@ -12,9 +12,9 @@ and any future measure) is a single `MatchModel` descriptor bundling
     pytree ``(lo, hi)``),
   * data preparation + index statistics (what GenieIndex.build_* duplicated),
   * the count-dtype policy (Bitmap-Counter bit-bounding, paper III-C),
-  * the multiload padding fill (a value that can never out-score real rows).
+  * the pad-row fill (a value that can never out-score real rows).
 
-GenieIndex, core.multiload, core.distributed, and launch.dryrun all resolve
+GenieIndex, SegmentedIndex, core.plan, and launch.dryrun all resolve
 engines through `get()` -- there is exactly one dispatch point in the system.
 Registering a new similarity measure is one `register(MatchModel(...))` call;
 see docs/ENGINES.md for the contract and a worked example.
@@ -83,8 +83,8 @@ class MatchModel:
 
     The canonical match signature is ``fn(data, queries) -> counts [Q, N]``
     where `queries` is this engine's canonical query pytree (produced by
-    `prepare_queries`).  Both `reference` and `kernel` use it, so multiload,
-    distributed sharding, and serving are engine-agnostic.
+    `prepare_queries`).  Both `reference` and `kernel` use it, so segmented
+    streaming, distributed sharding, and serving are engine-agnostic.
     """
 
     engine: Engine
@@ -102,7 +102,8 @@ class MatchModel:
     postings_count: Callable[[jnp.ndarray], int]
     # default count-domain bound, or None when the caller must supply one
     default_max_count: Callable[[jnp.ndarray], Optional[int]]
-    # multiload row fill: padded rows must never beat real rows
+    # pad-row fill (`plan.pad_to_multiple`: SEGMENTED or DISTRIBUTED plans
+    # with n_objects): padded rows must never beat real rows
     pad_value: Any = -1
     # seeded conformance data: (np rng, n, q) -> (raw_data, raw_queries,
     # max_count | None).  Engines that provide it get the engine-matrix
@@ -123,7 +124,7 @@ class MatchModel:
     # fn(data, queries, k) -> (ids, counts) candidate buffers [Q, n_tiles*kc]
     # in per-tile (count desc, id asc) order, pads id -1 / count -1
     packed_fused_topk: Optional[Callable[[jnp.ndarray, Any, int], tuple]] = None
-    # multiload row fill in the packed domain (same never-out-scores contract
+    # pad-row fill in the packed domain (same never-out-scores contract
     # as pad_value; pad rows are id-masked upstream regardless)
     packed_pad_value: Any = None
     # packed footprint in bytes, computed from the WIDE prepared array
@@ -316,9 +317,9 @@ def resolve_match_fn(engine, use_kernel: bool = False,
                      signature_layout: SignatureLayout | str = SignatureLayout.WIDE):
     """Engine/str/MatchModel/callable -> canonical match callable.
 
-    Raw callables pass through untouched (back-compat for code that hands a
-    bare ``fn(data, queries)`` to distributed/multiload search) -- the caller
-    owns the layout contract in that case.
+    Raw callables pass through untouched (code that plans a bare
+    ``fn(data, queries)``) -- the caller owns the layout contract in that
+    case.
     """
     if callable(engine) and not isinstance(engine, (MatchModel, Engine, str)):
         return engine

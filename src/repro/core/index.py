@@ -5,17 +5,18 @@ vectors / discretized tuples) and resolves *everything* engine-specific --
 data preparation, query canonicalisation, kernel-vs-reference match dispatch,
 index statistics, count-domain bounds -- through the MatchModel registry
 (core/engines.py).  Searches are thin adapters over the unified planner
-(core/plan.py): `search` builds a MONOLITHIC QueryPlan, `search_multiload`
-a MULTILOAD plan, and both delegate to the one executor that owns match
-dispatch, pad masking, top-k selection, and merging (docs/EXECUTION.md).
+(core/plan.py): `search` builds a MONOLITHIC QueryPlan and delegates to the
+one executor that owns match dispatch, pad masking, top-k selection, and
+merging (docs/EXECUTION.md).
 
     index = GenieIndex.build(Engine.EQ, sigs)            # generic builder
     index = GenieIndex.build_lsh(sigs, max_count=m)      # named alias
     result = index.search(query_sigs, k=100)             # TopKResult
 
-Larger-than-memory data uses `search_multiload` (all registered engines);
-multi-device search goes through core.distributed (the index there is just
-the sharded data matrix plus an Engine name).
+Larger-than-memory data is a `SegmentedIndex` (core/segments.py), whose
+SEGMENTED host loop streams one part at a time through the device -- the
+paper's multiple loading; multi-device search is a DISTRIBUTED plan over
+the sharded data matrix (core/distributed.py places it).
 """
 from __future__ import annotations
 
@@ -159,24 +160,3 @@ class GenieIndex:
             tune_width=int(self.data.shape[1]),
         )
         return _plan.execute(plan, self.data, self.prepare_queries(queries))
-
-    def search_multiload(self, queries, k: int, n_parts: int,
-                         method: TopKMethod = TopKMethod.CPQ,
-                         candidate_cap: int | None = None,
-                         tile_overrides=None, autotune=None) -> TopKResult:
-        """Paper section III-D: split this index into parts and stream them.
-
-        Works for every registered engine: the planned layout pads parts with
-        the engine's neutral fill and the executor masks pad rows out of the
-        merged result.
-        """
-        plan = _plan.plan_search(
-            self.engine, k, self.max_count, layout=_plan.Layout.MULTILOAD,
-            n_parts=n_parts, n_objects=self.stats.n_objects, method=method,
-            candidate_cap=candidate_cap, use_kernel=self.use_kernel,
-            signature_layout=self.signature_layout,
-            tile_overrides=tile_overrides, autotune=autotune,
-            tune_width=int(self.data.shape[1]),
-        )
-        chunks = _plan.pad_and_stack(plan, self.data)
-        return _plan.execute(plan, chunks, self.prepare_queries(queries))

@@ -1,46 +1,41 @@
 """Unified query planning + execution: one plan -> execute pipeline for every
 GENIE search path.
 
-The execution layer had quietly forked into four near-copies of the same
-loop -- `GenieIndex.search`, `SegmentedIndex.search`/`search_multiload`,
-`multiload_search(_host)`, and the distributed shard_map step each re-derived
-engine dispatch, pad masking, per-part k-clamping, and top-k merging.  This
-module is the consolidation (the Faiss plan/execute split of Johnson et al.
-1702.08734, FLASH's host-orchestrated part streaming for memory-bound
+Every search door -- `GenieIndex.search`, `SegmentedIndex.search`, and the
+mesh-sharded serving path -- describes its search as a `QueryPlan` and
+delegates here, so engine dispatch, pad masking, per-part k-clamping, and
+top-k merging live in one place (the Faiss plan/execute split of Johnson et
+al. 1702.08734, FLASH's host-orchestrated part streaming for memory-bound
 corpora):
 
   * `plan_search(...)` is the single entry point that describes a search as a
-    `QueryPlan`: the engine, the part layout (monolithic / segments /
-    multiload parts / mesh shards), the pad policy, the per-part k clamp, and
-    the merge strategy.
+    `QueryPlan`: the engine, the part layout (monolithic / streamed parts /
+    mesh shards), the pad policy, the per-part k clamp, and the merge
+    strategy.
   * `execute(plan, data, queries)` is the ONLY code in the system that calls
     match kernels, pad masking, `select_topk`, and the `core/merge` buffers.
-    Every legacy entry point is now a thin adapter that builds a plan and
-    delegates here.
   * Compiled executables are cached per plan (`_EXEC_CACHE`): repeated
     queries with the same (engine, layout shape, k, method, use_kernel)
     reuse the jitted program instead of re-tracing.  `trace_count(plan)`
     exposes the per-plan trace counter so tests (and the serve-latency
     benchmark) can assert cache hits.
 
-The four layouts and their merge strategies:
+The three layouts and their merge strategies:
 
   MONOLITHIC   one device-resident part; selection IS the merge.
-  SEGMENTED    host loop over immutable per-segment parts (heterogeneous
-               rows); per-part buffers of width min(k, rows) merged exactly
-               by `merge_ragged` (parts partition the object set).
-  MULTILOAD    paper section III-D part streaming: either a stacked
-               [C, Nc, ...] lax.scan with an incremental pairwise merge
-               (device-resident stack) or the literal host loop
-               (`host_loop=True`, parts swapped through the device).
+  SEGMENTED    host loop over parts of any (heterogeneous) row counts, each
+               put on the device in turn -- the paper's multiple loading
+               (section III-D) for corpora larger than device memory.  Per-
+               part buffers of width min(k, rows) merge exactly by
+               `merge_ragged` (parts partition the object set).
   DISTRIBUTED  mesh shards under shard_map; per-shard buffers all-gathered
                and merged collectively (optionally hierarchically: pod-local
                first, then across pods).
 
-Invariants owned here (and deleted from the four former copies):
-pad-never-in-topk (counts of rows with global id >= n_objects are forced to
--1 *before* selection), the (count desc, id asc) tie-break (stable buffer
-merges over id-ascending parts), and the ragged per-part k clamp.
+Invariants owned here: pad-never-in-topk (counts of rows with global id >=
+n_objects are forced to -1 *before* selection), the (count desc, id asc)
+tie-break (stable buffer merges over id-ascending parts), and the ragged
+per-part k clamp.
 """
 from __future__ import annotations
 
@@ -71,8 +66,7 @@ class Layout(str, enum.Enum):
     """Part layout of a planned search (the taxonomy in docs/EXECUTION.md)."""
 
     MONOLITHIC = "monolithic"      # one device-resident data matrix
-    SEGMENTED = "segmented"        # host loop over sealed per-batch segments
-    MULTILOAD = "multiload"        # streamed index parts (scan or host loop)
+    SEGMENTED = "segmented"        # host loop streaming parts through the device
     DISTRIBUTED = "distributed"    # object shards across a device mesh
 
 
@@ -92,7 +86,6 @@ class QueryPlan:
     engine: Optional[Engine] = None    # None when `match` is a raw callable
     pad_value: Any = None              # engine fill for padded rows
     fused_hist: bool = False           # single-device fused Pallas histogram
-    host_loop: bool = False            # MULTILOAD: host streaming vs lax.scan
     hierarchical: bool = False         # DISTRIBUTED: pod-local merge first
     mesh_axes: tuple[str, ...] = ()    # DISTRIBUTED: mesh axis names
     # signature storage format the match fn expects (core/packing.py); part
@@ -130,7 +123,7 @@ class QueryPlan:
 
     def part_k(self, rows: int) -> int:
         """Ragged k clamp: a part smaller than k contributes only
-        min(k, rows) candidates (host-loop layouts)."""
+        min(k, rows) candidates (SEGMENTED parts)."""
         return min(self.params.k, rows)
 
     def merge_strategy(self) -> str:
@@ -138,8 +131,6 @@ class QueryPlan:
             return "none"
         if self.layout == Layout.DISTRIBUTED:
             return "collective-hierarchical" if self.hierarchical else "collective"
-        if self.layout == Layout.MULTILOAD and not self.host_loop:
-            return "incremental-pairwise"
         return "ragged-buffer"
 
     def describe(self) -> dict:
@@ -161,7 +152,6 @@ class QueryPlan:
             n_objects=self.n_objects,
             pad_rows=self.pad_rows,
             merge=self.merge_strategy(),
-            host_loop=self.host_loop,
             hierarchical=self.hierarchical,
             mesh_axes=list(self.mesh_axes),
             fused_hist=self.fused_hist,
@@ -180,12 +170,10 @@ def plan_search(
     *,
     layout: Layout = Layout.MONOLITHIC,
     part_rows: Optional[Sequence[int]] = None,
-    n_parts: Optional[int] = None,
     n_objects: Optional[int] = None,
     method: TopKMethod = TopKMethod.CPQ,
     candidate_cap: Optional[int] = None,
     use_kernel: bool = True,
-    host_loop: bool = False,
     hierarchical: bool = False,
     mesh_axes: Sequence[str] = (),
     signature_layout: SignatureLayout | str = SignatureLayout.WIDE,
@@ -199,13 +187,13 @@ def plan_search(
     parts, fix the pad policy and merge strategy, return the QueryPlan.
 
     `engine` may be an Engine, its string value, a MatchModel, or a raw
-    canonical callable ``fn(data, queries) -> counts`` (back-compat with code
-    that hands bare match functions to multiload/distributed search).
+    canonical callable ``fn(data, queries) -> counts``.
 
-    Layout shape: pass `part_rows` (explicit, possibly ragged part sizes) or
-    `n_parts` with `n_objects` (an even split padded up to divisibility --
-    the classic multiload partition).  DISTRIBUTED plans defer the shape to
-    compile time (shard_map splits whatever data arrives).
+    Layout shape: pass `part_rows` (explicit, possibly ragged part sizes;
+    `segments.even_segments` gives an even split).  `n_objects` marks the
+    rows at and past it as engine-filled pad (`pad_to_multiple`), masked out
+    of every candidate buffer.  DISTRIBUTED plans defer the shape to compile
+    time (shard_map splits whatever data arrives).
 
     `signature_layout` selects the storage format the data/queries arrive in
     (core/packing.py): PACKED plans dispatch the packed match fns and -- on
@@ -217,9 +205,8 @@ def plan_search(
     and ROUTED_VERIFIED plans execute against a Router built from segment
     summaries (`execute(..., router=...)`) and skip the parts/shards the
     router rules out.  Routing prunes host-streamed parts or mesh shards, so
-    it requires a part-structured layout: SEGMENTED, MULTILOAD with
-    host_loop=True, or DISTRIBUTED -- the single-program scans (MONOLITHIC,
-    scanned MULTILOAD) have nothing to skip and reject it here.
+    it requires SEGMENTED or DISTRIBUTED -- a MONOLITHIC plan is one
+    device program with nothing to skip and rejects it here.
 
     `tile_overrides` binds kernel tile sizes (tile_q/tile_n/tile_v/tile_m --
     the knobs kernels/ops.py accepts) onto the kernel dispatch path; it is
@@ -286,41 +273,22 @@ def plan_search(
         match = model.match_fn(use_kernel, sig_layout, tiles)
 
     layout = Layout(layout)
-    if part_rows is None and n_parts is not None:
-        if n_parts < 1:
-            raise ValueError(f"n_parts must be >= 1, got {n_parts}")
-        if n_objects is None:
-            raise ValueError("an even multiload split needs n_objects")
-        per = -(-n_objects // n_parts)
-        part_rows = (per,) * n_parts
     rows = tuple(int(r) for r in part_rows) if part_rows is not None else ()
-    if layout in (Layout.SEGMENTED, Layout.MULTILOAD) and not rows:
-        raise ValueError(f"{layout.value} layout requires part_rows (or n_parts)")
+    if layout == Layout.SEGMENTED and not rows:
+        raise ValueError(f"{layout.value} layout requires part_rows")
     if layout == Layout.MONOLITHIC and len(rows) > 1:
         raise ValueError(f"monolithic layout got {len(rows)} parts")
     if any(r < 1 for r in rows):
         raise ValueError(f"part_rows must be positive, got {rows}")
-    if layout == Layout.MULTILOAD and not host_loop and len(set(rows)) > 1:
-        # the scanned executor derives global-id offsets as i * part_rows[0];
-        # ragged parts would silently globalise wrong ids
-        raise ValueError(
-            f"scanned multiload layout requires uniform part_rows, got {rows}; "
-            f"pass host_loop=True to stream ragged parts"
-        )
 
     routing = Routing(routing)
-    host_looped = bool(host_loop) and layout == Layout.MULTILOAD
     if routing is not Routing.NONE:
-        routable = (layout == Layout.SEGMENTED or host_looped
-                    or layout == Layout.DISTRIBUTED)
-        if not routable:
+        if layout == Layout.MONOLITHIC:
             raise ValueError(
                 f"routing={routing.value!r} prunes host-streamed parts or "
-                f"mesh shards; a {layout.value} plan"
-                f"{'' if host_loop or layout != Layout.MULTILOAD else ' (scanned)'}"
-                f" is one device program with nothing to skip -- use "
-                f"routing='none', or a SEGMENTED / MULTILOAD host_loop / "
-                f"DISTRIBUTED layout"
+                f"mesh shards; a {layout.value} plan is one device program "
+                f"with nothing to skip -- use routing='none', or a SEGMENTED "
+                f"or DISTRIBUTED layout"
             )
         if nprobe is not None and int(nprobe) < 1:
             raise ValueError(f"nprobe must be >= 1, got {nprobe}")
@@ -331,14 +299,13 @@ def plan_search(
     params = SearchParams(k=k, max_count=max_count, method=method,
                           candidate_cap=candidate_cap, use_kernel=use_kernel)
     # The fused Pallas histogram runs on the single-device paths only; the
-    # scan / shard_map paths keep the jnp reference histogram (unchanged
-    # behaviour of the four pre-planner copies).
+    # shard_map path keeps the jnp reference histogram.
     fused = use_kernel and layout in (Layout.MONOLITHIC, Layout.SEGMENTED)
     # The fused match->count->local-top-k kernel replaces the whole
     # count+select pipeline.  Same single-device gating as fused_hist, plus
     # n_objects None: the kernel masks pad columns by *physical* row id, so
-    # engine-filled pad rows (multiload stacks, mesh divisibility) must not
-    # be present -- those layouts keep the packed count kernel + the
+    # engine-filled pad rows (`pad_to_multiple`, e.g. mesh divisibility)
+    # must not be present -- padded plans keep the packed count kernel + the
     # structural _mask_pad_counts instead.
     fused_topk = None
     if (model is not None and sig_layout is SignatureLayout.PACKED
@@ -351,7 +318,6 @@ def plan_search(
         n_objects=n_objects, engine=model.engine if model else None,
         pad_value=model.pad_value_for(sig_layout) if model else None,
         fused_hist=fused,
-        host_loop=host_looped,
         hierarchical=bool(hierarchical), mesh_axes=tuple(mesh_axes),
         signature_layout=sig_layout, fused_match=fused_topk,
         routing=routing, nprobe=nprobe, tile_overrides=tiles,
@@ -447,26 +413,6 @@ def pad_to_multiple(data: np.ndarray, multiple: int, pad_value) -> tuple[np.ndar
     return data, n
 
 
-def pad_and_stack(plan: QueryPlan, data: jnp.ndarray) -> jnp.ndarray:
-    """Materialise a MULTILOAD scan layout from a monolithic data matrix:
-    pad with the plan's engine fill and stack into [C, Nc, ...] chunks."""
-    if plan.layout != Layout.MULTILOAD or not plan.part_rows:
-        raise ValueError(f"pad_and_stack needs a MULTILOAD plan, got {plan.layout}")
-    if plan.pad_value is None:
-        raise ValueError("pad_and_stack needs an engine-resolved plan "
-                         "(raw-callable plans carry no pad fill)")
-    per = plan.part_rows[0]
-    want = per * plan.n_parts
-    n = int(data.shape[0])
-    if n > want:
-        raise ValueError(f"data has {n} rows but the plan lays out {want}")
-    if n < want:
-        fill = jnp.full((want - n,) + data.shape[1:], plan.pad_value,
-                        dtype=data.dtype)
-        data = jnp.concatenate([data, fill], axis=0)
-    return data.reshape(plan.n_parts, per, *data.shape[1:])
-
-
 # ---------------------------------------------------------------------------
 # The executable cache + per-plan trace counter
 # ---------------------------------------------------------------------------
@@ -485,24 +431,18 @@ def _note_trace(key) -> None:
     _TRACE_COUNTS[key] = _TRACE_COUNTS.get(key, 0) + 1
 
 
-def _is_host_loop(plan: QueryPlan) -> bool:
-    return plan.layout == Layout.SEGMENTED or (
-        plan.layout == Layout.MULTILOAD and plan.host_loop)
-
-
 def trace_count(plan: QueryPlan) -> int:
     """How many times this plan's executables have been traced (a cache hit
-    leaves the counter unchanged).  Host-loop plans sum their per-part
+    leaves the counter unchanged).  SEGMENTED plans sum their per-part
     kernels (parts with equal row counts share one); distributed plans sum
     across meshes."""
-    if _is_host_loop(plan):
+    if plan.layout == Layout.SEGMENTED:
         return sum(_TRACE_COUNTS.get(k, 0)
                    for k in {_part_key(plan, r) for r in plan.part_rows})
     if plan.layout == Layout.DISTRIBUTED:
         return sum(v for k, v in _TRACE_COUNTS.items()
                    if k[0] == "dist" and k[1] == plan)
-    tag = "mono" if plan.layout == Layout.MONOLITHIC else "scan"
-    return _TRACE_COUNTS.get((tag, plan), 0)
+    return _TRACE_COUNTS.get(("mono", plan), 0)
 
 
 def plan_cache_size() -> int:
@@ -606,32 +546,6 @@ def _build_monolithic(plan: QueryPlan, key):
     return jax.jit(run)
 
 
-def _build_scan(plan: QueryPlan, key):
-    nc = plan.part_rows[0]
-    k = plan.params.k
-
-    def run(chunks: jnp.ndarray, queries: Any) -> TopKResult:
-        _note_trace(key)
-        q = jax.tree_util.tree_leaves(queries)[0].shape[0]
-        init = (jnp.full((q, k), -1, dtype=jnp.int32),
-                jnp.full((q, k), -1, dtype=jnp.int32))
-
-        def step(carry, xs):
-            best_ids, best_counts = carry
-            part, chunk_idx = xs
-            gids, gcnt = _part_topk(plan, part, queries, chunk_idx * nc)
-            with tracing.scope(tracing.MERGE):
-                ids = jnp.concatenate([best_ids, gids[:, :k]], axis=-1)
-                cnt = jnp.concatenate([best_counts, gcnt[:, :k]], axis=-1)
-                return _cpq.topk_from_candidates(ids, cnt, k), None
-
-        xs = (chunks, jnp.arange(plan.n_parts, dtype=jnp.int32))
-        (ids, counts), _ = jax.lax.scan(step, init, xs)
-        return TopKResult(ids=ids, counts=counts, threshold=counts[:, -1])
-
-    return jax.jit(run)
-
-
 def _part_key(plan: QueryPlan, rows: int) -> tuple:
     """Cache key of a host-loop per-part kernel: only what the part program
     actually closes over -- NOT the whole plan, so growing the corpus (new
@@ -643,7 +557,7 @@ def _part_key(plan: QueryPlan, rows: int) -> tuple:
 
 
 def _part_fn(plan: QueryPlan, rows: int):
-    """Cached per-part jitted kernel for the host-loop layouts: parts with
+    """Cached per-part jitted kernel for the SEGMENTED host loop: parts with
     the same row count share one compiled program across searches AND across
     corpus growth, so a 40-segment corpus of equal seals compiles once."""
     key = _part_key(plan, rows)
@@ -719,7 +633,8 @@ def _route(plan: QueryPlan, router: Optional["_routing.Router"],
             f"a routing={plan.routing.value!r} plan needs router= (built "
             f"from segment summaries, e.g. SegmentedIndex.router())"
         )
-    if _is_host_loop(plan) and tuple(router.part_rows) != plan.part_rows:
+    if plan.layout == Layout.SEGMENTED \
+            and tuple(router.part_rows) != plan.part_rows:
         raise ValueError(
             f"router summarises parts {tuple(router.part_rows)} but the plan "
             f"lays out {plan.part_rows}; rebuild the router from the current "
@@ -746,7 +661,7 @@ def _skipped_could_contribute(result: TopKResult, ubs: np.ndarray,
 
 def _run_host_parts(plan: QueryPlan, parts, queries, router=None,
                     route_queries=None) -> TopKResult:
-    """Host-orchestrated part streaming (SEGMENTED and MULTILOAD host_loop),
+    """Host-orchestrated part streaming (the SEGMENTED layout),
     with coarse routing when the plan asks for it: ROUTED scans only the
     router-selected parts; ROUTED_VERIFIED additionally checks the skipped
     parts' upper bounds against the routed threshold and falls back to the
@@ -833,10 +748,9 @@ def executable(plan: QueryPlan, mesh: Optional[jax.sharding.Mesh] = None):
     (engine, layout shape, k, method, use_kernel) was planned before.
 
     Returns ``fn(data, queries) -> TopKResult`` where `data`'s form follows
-    the layout: one array (MONOLITHIC / DISTRIBUTED-sharded), a stacked
-    [C, Nc, ...] array (MULTILOAD scan), or a list of per-part arrays
-    (SEGMENTED / MULTILOAD host loop).  Routed DISTRIBUTED executables take
-    a third operand, `shard_active` int32 [n_shards]; routed host-loop
+    the layout: one array (MONOLITHIC / DISTRIBUTED-sharded) or a list of
+    per-part arrays (SEGMENTED).  Routed DISTRIBUTED executables take a
+    third operand, `shard_active` int32 [n_shards]; routed SEGMENTED
     callables take `router=` / `route_queries=` keywords (both orchestrated
     by `execute`)."""
     if plan.layout == Layout.DISTRIBUTED:
@@ -847,11 +761,8 @@ def executable(plan: QueryPlan, mesh: Optional[jax.sharding.Mesh] = None):
     if plan.layout == Layout.MONOLITHIC:
         key = ("mono", plan)
         return _cached(key, lambda: _build_monolithic(plan, key))
-    if plan.layout == Layout.MULTILOAD and not plan.host_loop:
-        key = ("scan", plan)
-        return _cached(key, lambda: _build_scan(plan, key))
-    # host-loop layouts: the python orchestration is free to rebuild; the
-    # per-part compiled kernels underneath are the cached hot path
+    # SEGMENTED: the python orchestration is free to rebuild; the per-part
+    # compiled kernels underneath are the cached hot path
     return lambda parts, queries, router=None, route_queries=None: \
         _run_host_parts(plan, parts, queries, router=router,
                         route_queries=route_queries)
@@ -907,7 +818,7 @@ def execute(plan: QueryPlan, data, queries,
             raise ValueError("a DISTRIBUTED plan executes on a mesh; pass mesh=")
         return _run_routed_sharded(plan, data, queries, mesh, router,
                                    route_queries)
-    if _is_host_loop(plan):
+    if plan.layout == Layout.SEGMENTED:
         return executable(plan, mesh=mesh)(data, queries, router=router,
                                            route_queries=route_queries)
     return executable(plan, mesh=mesh)(data, queries)

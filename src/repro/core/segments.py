@@ -11,14 +11,18 @@ and merges the cap-sized candidate buffers exactly, and
 `compact(max_segments)` coalesces adjacent segments so steady-state search
 cost stays flat as the corpus grows.
 
+The SEGMENTED host loop puts one segment at a time on the device, so it is
+also the paper's multiple loading (section III-D) for corpora larger than
+device memory: seal the corpus as `even_segments(n, parts)` batches (with
+`host_resident=True` to keep them in host memory) and search as usual.
+
 The merge is exact, not approximate: segments *partition* the object set, so
 an object's match count is computed entirely inside its own segment (the same
-invariant multiload streaming and the distributed shard merge already rely
-on).  Any global top-k member is a top-min(k, n_seg) member of its segment,
-hence per-segment buffers of width min(k, n_seg) always contain the global
-top-k, and the merged ordering (count desc, global id asc) is identical to a
-monolithic search -- ids and counts match exactly for every registered
-engine (tests/test_segments.py).
+invariant the distributed shard merge relies on).  Any global top-k member
+is a top-min(k, n_seg) member of its segment, hence per-segment buffers of
+width min(k, n_seg) always contain the global top-k, and the merged ordering
+(count desc, global id asc) is identical to a monolithic search -- ids and
+counts match exactly for every registered engine (tests/test_segments.py).
 
 Compaction only ever merges *adjacent* segments: global ids are assigned by
 cumulative segment offset in append order, and concatenating neighbours
@@ -210,58 +214,16 @@ class SegmentedIndex:
         (serve/retrieval.py keys it on the corpus fingerprint) skip the
         per-search rebuild; ignored when routing is NONE.
 
-        `autotune` consults the measured-knob cache (core/autotune.py); when
-        the tuned entry prefers the MULTILOAD host loop over the SEGMENTED
-        merge for this shape, the search delegates there -- both layouts
-        stream the same per-part arrays and merge bit-for-bit identically,
-        so the switch is pure orchestration cost.
+        `autotune` consults the measured-knob cache (core/autotune.py) for
+        the knobs the caller left unset.
         """
         if not self.segments:
             raise ValueError("empty SegmentedIndex: add() first")
         routing = _routing.Routing(routing)
-        if autotune is not None and autotune is not False:
-            from repro.core import autotune as _autotune
-
-            entry = _autotune.consult(
-                autotune, engine=self.engine,
-                signature_layout=self.signature_layout,
-                n=self.n_objects, width=self._tune_width(),
-            )
-            if entry is not None and entry.layout == "multiload_host":
-                return self.search_multiload(
-                    queries, k, method=method, candidate_cap=candidate_cap,
-                    routing=routing, nprobe=nprobe, router=router,
-                    tile_overrides=tile_overrides, autotune=autotune,
-                )
         plan = _plan.plan_search(
             self.engine, k, self.max_count, layout=_plan.Layout.SEGMENTED,
             part_rows=tuple(self.segment_rows), method=method,
             candidate_cap=candidate_cap, use_kernel=self.use_kernel,
-            signature_layout=self.signature_layout,
-            routing=routing, nprobe=nprobe,
-            tile_overrides=tile_overrides, autotune=autotune,
-            tune_width=self._tune_width(),
-        )
-        return self._routed_execute(plan, queries, routing, router=router)
-
-    def search_multiload(self, queries, k: int,
-                         method: TopKMethod = TopKMethod.CPQ,
-                         candidate_cap: int | None = None,
-                         routing: _routing.Routing | str = _routing.Routing.NONE,
-                         nprobe: int | None = None,
-                         router: _routing.Router | None = None,
-                         tile_overrides=None, autotune=None) -> TopKResult:
-        """Stream the segments through the device one at a time (paper
-        section III-D's host loop) -- segments of heterogeneous sizes are the
-        parts, so nothing is re-concatenated or re-padded."""
-        if not self.segments:
-            raise ValueError("empty SegmentedIndex: add() first")
-        routing = _routing.Routing(routing)
-        plan = _plan.plan_search(
-            self.engine, k, self.max_count, layout=_plan.Layout.MULTILOAD,
-            part_rows=tuple(self.segment_rows), n_objects=self.n_objects,
-            method=method, candidate_cap=candidate_cap,
-            use_kernel=self.use_kernel, host_loop=True,
             signature_layout=self.signature_layout,
             routing=routing, nprobe=nprobe,
             tile_overrides=tile_overrides, autotune=autotune,
@@ -337,7 +299,7 @@ class SegmentedIndex:
         concatenated on the host in global-id order, row count padded up to
         a multiple of `pad_multiple` with the engine's pad fill.  A sharded
         `jax.device_put` of the result sends each device only its shard.
-        Pass `n_objects` to `distributed.make_search_step` so pad rows are
+        Pass `n_objects` to a DISTRIBUTED `plan_search` so pad rows are
         masked out of every shard's candidate buffer."""
         if not self.segments:
             raise ValueError("empty SegmentedIndex: add() first")

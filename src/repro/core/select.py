@@ -5,8 +5,8 @@ narrowing / full sort) and optionally consumes the fused Pallas histogram
 (kernels/cpq_hist) so the Gate reconstruction never re-reads the counts
 matrix on the kernel path.
 
-Its only caller is the unified executor (core/plan.py) -- monolithic,
-segmented, multiload, and distributed layouts all select through the same
+Its only caller is the unified executor (core/plan.py) -- the monolithic,
+segmented, and distributed layouts all select through the same
 per-part step there, which is what makes the selection strategy a
 *parameter* of a search rather than a property of the call site: every
 layout honours `method` exactly like single-device search does.

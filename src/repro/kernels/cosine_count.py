@@ -10,7 +10,8 @@ product, so the compare rides the MXU as a tiled matmul with bf16 +-1 inputs
 tile, so the running sum and the final (V + dot) // 2 shift are pure integer
 arithmetic: the kernel emits int32 counts with no f32 magnitude bound on V
 (the old f32 accumulator capped exactness at 2^24).  Zero pad rows
-(multiload fill) floor to V // 2 and are masked upstream by global id.
+(`plan.pad_to_multiple` fill) floor to V // 2 and are masked upstream by
+global id.
 """
 from __future__ import annotations
 

@@ -138,7 +138,7 @@ def _lower_lm(cfg, shape, mesh, accum_override=None):
             if accum_override is not None:
                 accum = accum_override
             # bf16 Adam moments for >100B models: f32 moments alone exceed
-            # 16 GB/chip at 256 chips for grok-1 (EXPERIMENTS.md Perf iter 7)
+            # 16 GB/chip at 256 chips for grok-1
             from repro.optim.adamw import AdamWConfig
 
             mdt = "bfloat16" if cfg.param_count() > 100e9 else "float32"
@@ -300,7 +300,7 @@ def run_genie_cell(dataset: str, mesh_kind: str) -> dict:
 
     # Input shapes/dtypes are dataset metadata; the match function itself is
     # resolved from the MatchModel registry by engine name inside
-    # make_search_step -- no per-engine dispatch here.
+    # plan_search -- no per-engine dispatch here.
     if ds.engine == "eq":
         # signature dtype: narrowest int that holds the rehash domain
         # (hillclimb C: int8 SIFT signatures quarter the dominant HBM stream)
@@ -402,7 +402,7 @@ def run_genie_cell(dataset: str, mesh_kind: str) -> dict:
     # the signature matrix once per query batch with VMEM-resident count
     # tiles; the XLA fallback engine recorded above re-reads its [Q, N]
     # accumulator every m/chunk scan step.  Both are reported; roofline uses
-    # the kernel model for GENIE rows (EXPERIMENTS.md section Roofline).
+    # the kernel model for GENIE rows.
     n_local = n // n_dev
     width = ds.m if ds.engine != "range" else ds.dim
     if ds.engine in ("minsum", "ip"):

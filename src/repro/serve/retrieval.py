@@ -319,9 +319,10 @@ class RetrievalService:
         """Autotune this service's serving shape against a representative
         query batch (core/autotune.py) and return the winning TunedEntry.
 
-        Measures the part-structured search the unmeshed path actually runs
-        -- tile sizes, fused preference, candidate_cap, SEGMENTED vs
-        MULTILOAD-host, and (when `routing` is routed) nprobe.  The winner
+        Measures the SEGMENTED search the unmeshed path actually runs (the
+        host loop that streams one segment at a time: the paper's multiple
+        loading) -- tile sizes, fused preference, candidate_cap, and (when
+        `routing` is routed) nprobe.  The winner
         lands in `cache` (defaulting to this service's `autotune` spec; an
         in-memory cache is created and installed when neither is set), so
         every later `search` picks the tuned knobs up automatically.

@@ -91,9 +91,10 @@ def test_tiled_plan_parity_packed(engine, method):
                  f"{engine.value} {method.value} packed tiled")
 
 
-def test_segmented_tiles_and_layout_switch_parity():
-    """Tile overrides ride the host part loop, and a tuned layout switch
-    (SEGMENTED -> MULTILOAD host) returns identical results."""
+def test_segmented_tiles_and_layout_switch_parity(tmp_path):
+    """Tile overrides ride the host part loop, and a cache file written when
+    entries still carried a part-layout choice ("layout") loads -- the stale
+    key is dropped -- and its tiles serve identical results."""
     model, raw, data, queries, mc = _case(Engine.EQ, n=150)
     seg = SegmentedIndex(engine=Engine.EQ, max_count=mc, use_kernel=True)
     for a, b in ((0, 40), (40, 41), (41, 150)):
@@ -102,15 +103,28 @@ def test_segmented_tiles_and_layout_switch_parity():
     _assert_same(seg.search(queries, k=5, tile_overrides={"tile_n": 128}),
                  base, "segmented tiled")
 
-    cache = autotune.AutotuneCache()
-    cache.put(autotune.TunedEntry(
-        engine="eq", signature_layout="wide",
-        n_bucket=autotune.shape_bucket(seg.n_objects),
-        w_bucket=autotune.shape_bucket(raw.shape[1]),
-        tile_overrides=(("tile_n", 128),), layout="multiload_host",
-        speedup=1.3))
+    old = dict(engine="eq", signature_layout="wide",
+               n_bucket=autotune.shape_bucket(seg.n_objects),
+               w_bucket=autotune.shape_bucket(raw.shape[1]),
+               tile_overrides={"tile_n": 128}, layout="multiload_host",
+               speedup=1.3)
+    path = tmp_path / "autotune.json"
+    path.write_text(json.dumps({
+        "version": autotune.CACHE_VERSION,
+        "fingerprint": autotune.hardware_fingerprint(),
+        "entries": {autotune.cache_key("eq", "wide", old["n_bucket"],
+                                       old["w_bucket"]): old}}))
+    cache = autotune.AutotuneCache(path)
+    (entry,) = cache.entries.values()
+    assert not hasattr(entry, "layout")
+    assert entry.tile_overrides == (("tile_n", 128),) and entry.speedup == 1.3
+    plan = plan_lib.plan_search(Engine.EQ, 5, mc,
+                                layout=plan_lib.Layout.SEGMENTED,
+                                part_rows=tuple(seg.segment_rows),
+                                autotune=cache, tune_width=raw.shape[1])
+    assert dict(plan.tile_overrides) == {"tile_n": 128}
     _assert_same(seg.search(queries, k=5, autotune=cache), base,
-                 "tuned layout switch")
+                 "tuned tiles from an old cache file")
 
 
 def test_genie_index_autotune_parity():

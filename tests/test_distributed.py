@@ -27,6 +27,7 @@ def test_distributed_search_matches_oracle():
     _run("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import distributed, match, cpq
+        from repro.core import plan as plan_lib
         from repro.core.types import SearchParams
         from repro.launch import mesh as mesh_lib
         for shape, axes in [((2,4), ('data','model')), ((2,2,2), ('pod','data','model'))]:
@@ -35,13 +36,16 @@ def test_distributed_search_matches_oracle():
             data = rng.integers(0, 6, (128, 16)).astype(np.int32)
             queries = rng.integers(0, 6, (4, 16)).astype(np.int32)
             params = SearchParams(k=7, max_count=16)
-            for maker in (distributed.make_search_step, distributed.make_hierarchical_search_step):
-                step = maker(mesh, params, match.match_eq)
+            for hier in (False, True):
+                plan = plan_lib.plan_search(
+                    match.match_eq, 7, 16, layout=plan_lib.Layout.DISTRIBUTED,
+                    mesh_axes=axes, hierarchical=hier)
                 dd = jax.device_put(data, distributed.data_sharding(mesh))
                 qq = jax.device_put(queries, distributed.replicated(mesh, 2))
-                res = step(dd, qq)
+                res = plan_lib.execute(plan, dd, qq, mesh=mesh)
                 want = cpq.sort_select(match.match_eq(jnp.asarray(data), jnp.asarray(queries)), params)
-                assert np.array_equal(np.asarray(res.counts), np.asarray(want.counts)), maker
+                assert np.array_equal(np.asarray(res.counts), np.asarray(want.counts)), (axes, hier)
+                assert np.array_equal(np.asarray(res.ids), np.asarray(want.ids)), (axes, hier)
         print('distributed search OK')
     """)
 

@@ -1,5 +1,5 @@
 """Engine conformance matrix: every registered engine x {reference, kernel}
-x {search, multiload, distributed} must return identical top-k ids/counts.
+x {search, segmented, distributed} must return identical top-k ids/counts.
 
 This is the standing acceptance harness for the registry's genericity claim:
 a new engine registered with an `example` generator (MatchModel.example) gets
@@ -19,7 +19,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import GenieIndex, cpq, engines, select
+from repro.core import GenieIndex, SegmentedIndex, cpq, engines, select
+from repro.core import plan as plan_lib
+from repro.core.segments import even_segments
 from repro.core.types import Engine, SearchParams, TopKMethod
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -68,18 +70,22 @@ def test_matrix_search_kernel_reference_parity(engine):
 @pytest.mark.parametrize("engine", MATRIX_ENGINES)
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_matrix_multiload_parity(engine, use_kernel):
-    """Streamed multiload (uneven split, both match paths) == full search."""
+    """SEGMENTED streaming over an even split into 1, 3 and 5 parts (uneven
+    row counts, both match paths) == the MONOLITHIC search."""
     model, data, queries, mc = _example(engine, n=97)   # uneven on purpose
     idx = GenieIndex.build(engine, data, max_count=mc, use_kernel=use_kernel)
     full = idx.search(queries, k=6)
     for n_parts in (1, 3, 5):
-        part = idx.search_multiload(queries, k=6, n_parts=n_parts)
-        _assert_same_topk(part, full,
+        seg = SegmentedIndex(engine=engine, max_count=mc, use_kernel=use_kernel)
+        for part in np.split(np.asarray(data),
+                             np.cumsum(even_segments(97, n_parts))[:-1]):
+            seg.add(part)
+        _assert_same_topk(seg.search(queries, k=6), full,
                           f"{engine.value} kernel={use_kernel} parts={n_parts}")
 
 
 def test_matrix_distributed_parity():
-    """Every engine x {reference, kernel} through the sharded search step (8
+    """Every engine x {reference, kernel} through a DISTRIBUTED plan (8
     forced CPU devices via subprocess: jax locks the device count at first
     init).  use_kernel=True runs the Pallas kernels *inside* shard_map."""
     env = dict(os.environ)
@@ -89,6 +95,7 @@ def test_matrix_distributed_parity():
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import distributed, engines, cpq
+        from repro.core import plan as plan_lib
         from repro.core.types import SearchParams
         from repro.launch import mesh as mesh_lib
 
@@ -105,8 +112,10 @@ def test_matrix_distributed_parity():
             want = cpq.sort_select(model.reference(data, queries),
                                    SearchParams(k=7, max_count=mx))
             for use_kernel in (False, True):
-                params = SearchParams(k=7, max_count=mx, use_kernel=use_kernel)
-                res = distributed.make_search_step(mesh, params, eng)(dd, qq)
+                plan = plan_lib.plan_search(
+                    eng, 7, mx, layout=plan_lib.Layout.DISTRIBUTED,
+                    use_kernel=use_kernel, mesh_axes=tuple(mesh.axis_names))
+                res = plan_lib.execute(plan, dd, qq, mesh=mesh)
                 assert np.array_equal(np.asarray(res.counts), np.asarray(want.counts)), \\
                     (eng, use_kernel)
                 assert np.array_equal(np.asarray(res.ids), np.asarray(want.ids)), \\
@@ -120,13 +129,29 @@ def test_matrix_distributed_parity():
 
 
 # ---------------------------------------------------------------------------
-# Pad-value conformance (the multiload fill contract)
+# Pad-value conformance (the pad_to_multiple fill contract)
 # ---------------------------------------------------------------------------
+
+def _padded_parts_search(idx: GenieIndex, queries, k: int, n_parts: int):
+    """Search `idx`'s stored rows padded with the engine fill
+    (`pad_to_multiple`) up to `n_parts` equal SEGMENTED parts, with
+    n_objects marking the pad tail."""
+    padded, n = plan_lib.pad_to_multiple(
+        np.asarray(idx.data), n_parts,
+        idx.model.pad_value_for(idx.signature_layout))
+    per = padded.shape[0] // n_parts
+    plan = plan_lib.plan_search(
+        idx.engine, k, idx.max_count, layout=plan_lib.Layout.SEGMENTED,
+        part_rows=(per,) * n_parts, n_objects=n, use_kernel=idx.use_kernel,
+        signature_layout=idx.signature_layout)
+    parts = [padded[i * per:(i + 1) * per] for i in range(n_parts)]
+    return plan_lib.execute(plan, parts, idx.prepare_queries(queries))
+
 
 @pytest.mark.parametrize("engine", MATRIX_ENGINES)
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_matrix_pad_rows_never_reach_topk(engine, use_kernel):
-    """Padded multiload rows can never enter the top-k, even when the last
+    """Engine-filled pad rows can never enter the top-k, even when the last
     part is almost entirely padding and k exceeds its real rows.  Pad columns
     are masked to count -1 before per-part selection, so the guarantee holds
     for every engine regardless of how its pad_value scores (COSINE's zero
@@ -135,7 +160,7 @@ def test_matrix_pad_rows_never_reach_topk(engine, use_kernel):
     model, data, queries, mc = _example(engine, n=n)
     idx = GenieIndex.build(engine, data, max_count=mc, use_kernel=use_kernel)
     # 8 parts of 7 -> last part has 1 real row + 6 pad rows; k=10 > real rows
-    res = idx.search_multiload(queries, k=10, n_parts=8)
+    res = _padded_parts_search(idx, queries, k=10, n_parts=8)
     ids = np.asarray(res.ids)
     counts = np.asarray(res.counts)
     assert ids.max() < n, f"{engine.value}: pad id {ids.max()} in top-k"
@@ -147,11 +172,14 @@ def test_matrix_pad_rows_never_reach_topk(engine, use_kernel):
 @pytest.mark.parametrize("engine", MATRIX_ENGINES)
 def test_matrix_pad_value_representable(engine):
     """The declared pad_value must survive the round-trip into the prepared
-    data dtype (the fill GenieIndex.search_multiload performs)."""
+    data dtype (the fill `pad_to_multiple` performs)."""
     model, data, _, _ = _example(engine, n=8)
-    fill = jnp.full((2,) + data.shape[1:], model.pad_value, dtype=data.dtype)
+    padded, n = plan_lib.pad_to_multiple(np.asarray(data), 10, model.pad_value)
+    assert n == 8 and padded.shape == (10,) + data.shape[1:]
+    fill = padded[n:]
     assert fill.dtype == data.dtype
-    assert bool(jnp.all(fill == jnp.asarray(model.pad_value).astype(data.dtype)))
+    assert bool(np.all(fill == np.asarray(model.pad_value).astype(data.dtype)))
+    assert np.array_equal(padded[:n], np.asarray(data))
 
 
 # ---------------------------------------------------------------------------
@@ -186,13 +214,13 @@ def test_matrix_packed_wide_parity(engine, use_kernel, method):
 @pytest.mark.parametrize("engine", PACKED_ENGINES)
 @pytest.mark.parametrize("use_kernel", [False, True])
 def test_matrix_packed_pad_rows_never_reach_topk(engine, use_kernel):
-    """The packed multiload fill (0 words / 255 bytes) can never enter the
+    """The packed pad fill (0 words / 255 bytes) can never enter the
     top-k -- same contract as the WIDE pad sweep above."""
     n = 50
     model, data, queries, mc = _example(engine, n=n)
     idx = GenieIndex.build(engine, data, max_count=mc, use_kernel=use_kernel,
                            signature_layout="packed")
-    res = idx.search_multiload(queries, k=10, n_parts=8)
+    res = _padded_parts_search(idx, queries, k=10, n_parts=8)
     ids = np.asarray(res.ids)
     counts = np.asarray(res.counts)
     assert ids.max() < n, f"{engine.value}: pad id {ids.max()} in top-k"
@@ -232,7 +260,7 @@ def test_matrix_tie_break_consistency(name, method):
     inputs: every path orders by (count desc, id asc) -- CPQ/SPQ fill their
     candidate buffers in id order and break count ties with a stable sort,
     lax.top_k returns the lowest index among ties.  Divergence here would
-    make multiload/distributed results depend on the selection method."""
+    make segmented/distributed results depend on the selection method."""
     counts = jnp.asarray(_degenerate_counts()[name])
     params = SearchParams(k=5, max_count=10, method=method)
     got = select.select_topk(counts, params)

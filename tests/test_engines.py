@@ -3,7 +3,8 @@
 The acceptance bar for the unified-engine refactor: all six engines (EQ,
 RANGE, MINSUM, IP, TANIMOTO, COSINE) resolve through the registry with
 kernel-vs-reference parity, the count-dtype policy is engine-uniform, and
-multiload/distributed searches agree with single-device results.  The
+segmented (streamed) and distributed searches agree with single-device
+results.  The
 exhaustive engine x path x match-impl sweep lives in test_engine_matrix.py.
 """
 import os
@@ -15,7 +16,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import GenieIndex, cpq, engines
+from repro.core import GenieIndex, SegmentedIndex, cpq, engines
+from repro.core.segments import even_segments
 from repro.core.types import Engine, SearchParams, TopKMethod
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -104,18 +106,24 @@ def test_search_methods_agree_per_engine(engine, method, rng):
 @pytest.mark.parametrize("engine", ALL_ENGINES)
 @pytest.mark.parametrize("n_parts", [1, 3, 5])
 def test_multiload_parity_all_engines(engine, n_parts, rng):
-    """Every registered engine streams through multiload, uneven splits
-    included (pad rows are engine-neutral and masked)."""
+    """Every registered engine streams through the SEGMENTED host loop over
+    an even split into 1, 3 and 5 parts, uneven row counts included."""
     data, queries, mc = _case(engine, rng, n=97)   # uneven on purpose
     idx = GenieIndex.build(engine, data, max_count=mc, use_kernel=False)
     full = idx.search(queries, k=6)
-    part = idx.search_multiload(queries, k=6, n_parts=n_parts)
+    seg = SegmentedIndex(engine=engine, max_count=idx.max_count,
+                         use_kernel=False)
+    for part in np.split(np.asarray(data),
+                         np.cumsum(even_segments(97, n_parts))[:-1]):
+        seg.add(part)
+    part = seg.search(queries, k=6)
     assert np.array_equal(np.asarray(full.counts), np.asarray(part.counts)), engine
+    assert np.array_equal(np.asarray(full.ids), np.asarray(part.ids)), engine
 
 
 def test_distributed_parity_all_engines():
-    """All four engines through the sharded search step (8 forced CPU devices
-    via subprocess: jax locks the device count at first init)."""
+    """All four engines through a DISTRIBUTED plan (8 forced CPU devices via
+    subprocess: jax locks the device count at first init)."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
@@ -123,6 +131,7 @@ def test_distributed_parity_all_engines():
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import distributed, engines, cpq
+        from repro.core import plan as plan_lib
         from repro.core.types import Engine, SearchParams
         from repro.launch import mesh as mesh_lib
 
@@ -141,11 +150,13 @@ def test_distributed_parity_all_engines():
                                (jnp.asarray(lo), jnp.asarray(lo + 3)), 6)
         for eng, (data, queries, mx) in cases.items():
             params = SearchParams(k=7, max_count=mx)
-            step = distributed.make_search_step(mesh, params, eng)
+            plan = plan_lib.plan_search(
+                eng, 7, mx, layout=plan_lib.Layout.DISTRIBUTED,
+                mesh_axes=tuple(mesh.axis_names))
             dd = jax.device_put(data, distributed.data_sharding(mesh))
             qq = jax.tree_util.tree_map(
                 lambda x: jax.device_put(x, distributed.replicated(mesh, 2)), queries)
-            res = step(dd, qq)
+            res = plan_lib.execute(plan, dd, qq, mesh=mesh)
             counts = engines.get(eng).match_fn(False)(jnp.asarray(data), queries)
             want = cpq.sort_select(counts, params)
             assert np.array_equal(np.asarray(res.counts), np.asarray(want.counts)), eng

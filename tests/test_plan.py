@@ -1,14 +1,12 @@
-"""Planner parity suite: execute(plan) must reproduce the pre-planner results
+"""Planner parity suite: execute(plan) must reproduce the sort-select oracle
 bit-for-bit for every engine x layout x selection method.
 
-The four legacy entry points (GenieIndex.search, SegmentedIndex.search /
-search_multiload, multiload_search_host, distributed.make_*_search_step) are
-now thin adapters over core/plan.py; this suite pins the consolidated
-executor to the behaviour the four copies had: identical ids, counts, and
-thresholds against the sort-select oracle, across
+Each index type has one door into core/plan.py (GenieIndex.search is
+MONOLITHIC, SegmentedIndex.search is SEGMENTED -- the paper's multiple
+loading -- and the mesh path plans DISTRIBUTED); this suite pins the one
+executor to identical ids, counts, and thresholds against the oracle, across
 
-    6 engines x {monolithic, segmented, multiload, distributed} x
-    {CPQ, SPQ, SORT}
+    6 engines x {monolithic, segmented, distributed} x {CPQ, SPQ, SORT}
 
 plus the plan cache contract (same layout shape -> no retrace, counted via
 the per-plan trace counter) and the sharded-serving parity leg
@@ -55,9 +53,9 @@ def _assert_same(got, want, label=""):
 @pytest.mark.parametrize("engine", ALL_ENGINES)
 @pytest.mark.parametrize("method", ALL_METHODS)
 def test_planner_layout_parity(engine, method):
-    """MONOLITHIC, SEGMENTED, MULTILOAD(scan), and MULTILOAD(host) plans all
-    reproduce the sort oracle's ids and counts exactly, and their thresholds
-    agree with the k-th count (Theorem 3.1)."""
+    """MONOLITHIC and SEGMENTED plans both reproduce the sort oracle's ids
+    and counts exactly, and their thresholds agree with the k-th count
+    (Theorem 3.1)."""
     k = 9
     model, raw, data, queries, mc = _case(engine)
     oracle = cpq.sort_select(
@@ -73,9 +71,6 @@ def test_planner_layout_parity(engine, method):
     results = {
         "monolithic": idx.search(queries, k=k, method=method),
         "segmented": seg.search(queries, k=k, method=method),
-        "multiload-scan": idx.search_multiload(queries, k=k, n_parts=4,
-                                               method=method),
-        "multiload-host": seg.search_multiload(queries, k=k, method=method),
     }
     for layout, got in results.items():
         _assert_same(got, oracle, f"{engine.value} {method.value} {layout}")
@@ -130,8 +125,8 @@ def test_plan_cache_no_retrace_on_repeat():
 
 
 def test_plan_cache_segmented_and_scan_paths():
-    """The host-loop per-part kernels and the scanned multiload executor are
-    cached too: a second identical search traces nothing new."""
+    """The host-loop per-part kernels and the monolithic executor are cached
+    side by side: a second identical search traces nothing new."""
     model, raw, data, queries, mc = _case(Engine.EQ)
     seg = SegmentedIndex(engine=Engine.EQ, max_count=mc, use_kernel=False)
     for a, b in zip(CUTS, CUTS[1:]):
@@ -140,12 +135,12 @@ def test_plan_cache_segmented_and_scan_paths():
     plan_lib.clear_plan_cache()
 
     seg.search(queries, k=5)
-    idx.search_multiload(queries, k=5, n_parts=4)
+    idx.search(queries, k=5)
     size_after_first = plan_lib.plan_cache_size()
     traces_after_first = sum(plan_lib._TRACE_COUNTS.values())
 
     seg.search(queries, k=5)
-    idx.search_multiload(queries, k=5, n_parts=4)
+    idx.search(queries, k=5)
     assert plan_lib.plan_cache_size() == size_after_first
     assert sum(plan_lib._TRACE_COUNTS.values()) == traces_after_first, \
         "repeat search re-traced a cached executable"
@@ -188,25 +183,9 @@ def test_plan_cache_is_bounded(monkeypatch):
     assert plan_lib.plan_cache_size() <= 3
 
 
-def test_scan_layout_rejects_ragged_parts():
-    """The scanned multiload executor derives offsets as i * part_rows[0];
-    ragged parts must be rejected at plan time (host_loop streams them)."""
-    with pytest.raises(ValueError, match="uniform part_rows"):
-        plan_lib.plan_search(Engine.EQ, 3, 16,
-                             layout=plan_lib.Layout.MULTILOAD,
-                             part_rows=(3, 50, 48), n_objects=101)
-    ok = plan_lib.plan_search(Engine.EQ, 3, 16,
-                              layout=plan_lib.Layout.MULTILOAD,
-                              part_rows=(3, 50, 48), n_objects=101,
-                              host_loop=True)
-    assert ok.host_loop
-
-
 def test_plan_search_validates_layout():
-    with pytest.raises(ValueError, match="n_parts"):
-        plan_lib.plan_search(Engine.EQ, 3, 16,
-                             layout=plan_lib.Layout.MULTILOAD, n_parts=0,
-                             n_objects=10)
+    assert [l.value for l in plan_lib.Layout] == [
+        "monolithic", "segmented", "distributed"]
     with pytest.raises(ValueError, match="part_rows"):
         plan_lib.plan_search(Engine.EQ, 3, 16,
                              layout=plan_lib.Layout.SEGMENTED)
@@ -219,21 +198,19 @@ def test_plan_search_validates_layout():
 
 def test_plan_layout_accounting_and_describe():
     plan = plan_lib.plan_search(
-        Engine.EQ, 7, 16, layout=plan_lib.Layout.MULTILOAD, n_parts=4,
-        n_objects=101, use_kernel=False,
+        Engine.EQ, 7, 16, layout=plan_lib.Layout.SEGMENTED,
+        part_rows=(26, 26, 26, 26), n_objects=101, use_kernel=False,
     )
     assert plan.part_rows == (26, 26, 26, 26)
     assert plan.pad_rows == 3 and plan.total_rows == 104
     assert plan.part_k(2) == 2 and plan.part_k(50) == 7
     d = plan.describe()
-    assert d["layout"] == "multiload" and d["engine"] == "eq"
-    assert d["merge"] == "incremental-pairwise" and d["pad_rows"] == 3
+    assert d["layout"] == "segmented" and d["engine"] == "eq"
+    assert d["merge"] == "ragged-buffer" and d["pad_rows"] == 3
 
-    host = plan_lib.plan_search(
-        Engine.EQ, 7, 16, layout=plan_lib.Layout.MULTILOAD,
-        part_rows=(3, 50, 48), n_objects=101, host_loop=True, use_kernel=False,
-    )
-    assert host.describe()["merge"] == "ragged-buffer"
+    mono = plan_lib.plan_search(Engine.EQ, 7, 16, part_rows=(101,),
+                                use_kernel=False)
+    assert mono.describe()["merge"] == "none"
     dist = plan_lib.plan_search(
         Engine.EQ, 7, 16, layout=plan_lib.Layout.DISTRIBUTED, n_objects=101,
         hierarchical=True, mesh_axes=("pod", "data", "model"),
@@ -242,16 +219,20 @@ def test_plan_layout_accounting_and_describe():
 
 
 def test_pad_and_stack_fills_with_engine_pad():
+    """pad_to_multiple appends the plan's engine fill up to the multiple and
+    leaves an already-divisible corpus untouched."""
     model, raw, data, queries, mc = _case(Engine.EQ)
     plan = plan_lib.plan_search(
-        Engine.EQ, 7, mc, layout=plan_lib.Layout.MULTILOAD, n_parts=4,
-        n_objects=101, use_kernel=False,
+        Engine.EQ, 7, mc, layout=plan_lib.Layout.SEGMENTED,
+        part_rows=(26, 26, 26, 26), n_objects=101, use_kernel=False,
     )
-    chunks = plan_lib.pad_and_stack(plan, data)
-    assert chunks.shape[:2] == (4, 26)
-    flat = np.asarray(chunks).reshape(104, -1)
-    assert np.array_equal(flat[:101], np.asarray(data))
-    assert np.all(flat[101:] == model.pad_value)
+    padded, n = plan_lib.pad_to_multiple(np.asarray(data), plan.n_parts,
+                                         plan.pad_value)
+    assert n == 101 and padded.shape[0] == plan.total_rows == 104
+    assert np.array_equal(padded[:101], np.asarray(data))
+    assert np.all(padded[101:] == model.pad_value)
+    same, n = plan_lib.pad_to_multiple(padded, 4, plan.pad_value)
+    assert n == 104 and same is padded
 
 
 # ---------------------------------------------------------------------------
@@ -285,9 +266,6 @@ def test_planner_packed_layout_parity(engine, method):
         results = {
             "monolithic": idx.search(queries, k=k, method=method),
             "segmented": seg.search(queries, k=k, method=method),
-            "multiload-scan": idx.search_multiload(queries, k=k, n_parts=4,
-                                                   method=method),
-            "multiload-host": seg.search_multiload(queries, k=k, method=method),
         }
         for layout, got in results.items():
             _assert_same(got, oracle,
@@ -313,10 +291,11 @@ def test_packed_plans_cache_separately_from_wide():
 
     seg = mk("SEGMENTED", part_rows=(40, 24), signature_layout="packed")
     assert seg.describe()["fused_match"]
-    # engine-filled pad rows (multiload stacks, mesh divisibility) are masked
+    # engine-filled pad rows (pad_to_multiple, mesh divisibility) are masked
     # by count, which the fused kernel cannot see -> no fusion there
-    ml = mk("MULTILOAD", n_parts=4, n_objects=101, signature_layout="packed")
-    assert not ml.describe()["fused_match"]
+    padded = mk("SEGMENTED", part_rows=(26,) * 4, n_objects=101,
+                signature_layout="packed")
+    assert not padded.describe()["fused_match"]
     dist = plan_lib.plan_search(
         Engine.COSINE, 5, 32, layout=plan_lib.Layout.DISTRIBUTED,
         n_objects=101, use_kernel=True, mesh_axes=("data",),
@@ -431,7 +410,7 @@ def test_planner_distributed_parity():
 
 
 def test_planner_distributed_packed_parity():
-    """PACKED x {reference, kernel} through the sharded search step equals
+    """PACKED x {reference, kernel} through a DISTRIBUTED plan equals
     the WIDE sort oracle: a packed segmented corpus exported by concat_data
     (pad rows filled with the packed pad value, masked via n_objects) and
     packed replicated queries, with the packed match running inside
@@ -443,6 +422,7 @@ def test_planner_distributed_packed_parity():
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import SegmentedIndex, cpq, distributed, engines
+        from repro.core import plan as plan_lib
         from repro.core.types import Engine, SearchParams, SignatureLayout
         from repro.launch import mesh as mesh_lib
 
@@ -464,11 +444,12 @@ def test_planner_distributed_packed_parity():
                 model.prepare_queries_for(rawq, SignatureLayout.PACKED),
                 distributed.replicated(mesh, 2))
             for use_kernel in (False, True):
-                params = SearchParams(k=7, max_count=mx, use_kernel=use_kernel)
-                step = distributed.make_search_step(
-                    mesh, params, eng, n_objects=n,
+                plan = plan_lib.plan_search(
+                    eng, 7, mx, layout=plan_lib.Layout.DISTRIBUTED,
+                    n_objects=n, use_kernel=use_kernel,
+                    mesh_axes=tuple(mesh.axis_names),
                     signature_layout=SignatureLayout.PACKED)
-                res = step(dd, qq)
+                res = plan_lib.execute(plan, dd, qq, mesh=mesh)
                 label = (eng.value, use_kernel)
                 assert np.array_equal(np.asarray(res.ids),
                                       np.asarray(want.ids)), label

@@ -4,8 +4,8 @@ in core/plan.py).
 The load-bearing guarantee: ROUTED_VERIFIED is bit-for-bit identical to the
 full scan -- identical ids, counts, AND thresholds -- across
 
-    6 engines x {CPQ, SPQ, SORT} x {SEGMENTED, MULTILOAD host loop,
-    DISTRIBUTED (subprocess, 8 forced CPU devices)}
+    6 engines x {CPQ, SPQ, SORT} x {SEGMENTED, DISTRIBUTED (subprocess,
+    8 forced CPU devices)}
 
 because the router's per-engine scores are true *upper bounds* on any row's
 match count, and the verified mode falls back to the full scan whenever a
@@ -78,23 +78,19 @@ def _assert_same(got, want, label=""):
 @pytest.mark.parametrize("method", ALL_METHODS)
 def test_routed_verified_equals_full_scan(engine, method):
     """ROUTED_VERIFIED at the most aggressive pruning (nprobe=1) reproduces
-    the full scan bit-for-bit on both host-loop layouts, and ROUTED with
+    the full scan bit-for-bit on the SEGMENTED host loop, and ROUTED with
     every probe open is trivially the full scan too."""
     k = 9
     model, raw, data, queries, mc = _case(engine)
     seg = _segmented(engine, raw, mc)
     n_seg = len(seg.segments)
-    for name, search in (("segmented", seg.search),
-                         ("multiload-host", seg.search_multiload)):
-        full = search(queries, k, method=method)
-        verified = search(queries, k, method=method,
+    full = seg.search(queries, k, method=method)
+    verified = seg.search(queries, k, method=method,
                           routing="routed_verified", nprobe=1)
-        _assert_same(verified, full,
-                     f"{engine.value} {method.value} {name} verified")
-        wide_open = search(queries, k, method=method,
+    _assert_same(verified, full, f"{engine.value} {method.value} verified")
+    wide_open = seg.search(queries, k, method=method,
                            routing="routed", nprobe=n_seg)
-        _assert_same(wide_open, full,
-                     f"{engine.value} {method.value} {name} all-probes")
+    _assert_same(wide_open, full, f"{engine.value} {method.value} all-probes")
 
 
 # ---------------------------------------------------------------------------
@@ -218,9 +214,8 @@ def test_plan_rejects_routing_on_single_program_layouts():
     with pytest.raises(ValueError, match="nothing to skip"):
         plan_lib.plan_search(Engine.EQ, 5, 16, routing="routed")
     with pytest.raises(ValueError, match="nothing to skip"):
-        plan_lib.plan_search(Engine.EQ, 5, 16,
-                             layout=plan_lib.Layout.MULTILOAD,
-                             n_parts=4, n_objects=101, routing="routed")
+        plan_lib.plan_search(Engine.EQ, 5, 16, part_rows=(101,),
+                             routing="routed_verified")
     with pytest.raises(ValueError, match="nprobe"):
         plan_lib.plan_search(Engine.EQ, 5, 16,
                              layout=plan_lib.Layout.SEGMENTED,
@@ -357,8 +352,8 @@ def test_service_search_accepts_iterator_queries():
 
 def test_candidate_cap_threads_through_host_loops_and_service(monkeypatch):
     """candidate_cap must reach the CPQ candidate buffer on every entry point
-    that forwards it: SegmentedIndex.search_multiload, the scanned
-    GenieIndex.search_multiload, and RetrievalService.search.  The observable
+    that forwards it: SegmentedIndex.search, GenieIndex.search, and
+    RetrievalService.search.  The observable
     is the cap the compaction kernel is traced with: max(candidate_cap, k),
     or the max(2k, k+16) default when unset."""
     seen = []
@@ -373,14 +368,14 @@ def test_candidate_cap_threads_through_host_loops_and_service(monkeypatch):
     seg = _segmented(Engine.EQ, raw, mc)
 
     plan_lib.clear_plan_cache()
-    seg.search_multiload(queries, 5, candidate_cap=31)
-    assert 31 in seen, f"multiload-host dropped candidate_cap: {seen}"
+    seg.search(queries, 5, candidate_cap=31)
+    assert 31 in seen, f"SegmentedIndex.search dropped candidate_cap: {seen}"
 
     seen.clear()
     plan_lib.clear_plan_cache()
     idx = GenieIndex.build(Engine.EQ, raw, max_count=mc, use_kernel=False)
-    idx.search_multiload(queries, 5, n_parts=4, candidate_cap=29)
-    assert 29 in seen, f"scanned multiload dropped candidate_cap: {seen}"
+    idx.search(queries, 5, candidate_cap=29)
+    assert 29 in seen, f"GenieIndex.search dropped candidate_cap: {seen}"
 
     seen.clear()
     plan_lib.clear_plan_cache()
@@ -392,7 +387,7 @@ def test_candidate_cap_threads_through_host_loops_and_service(monkeypatch):
 
     seen.clear()
     plan_lib.clear_plan_cache()
-    seg.search_multiload(queries, 5)
+    seg.search(queries, 5)
     assert 21 in seen, f"default cap should be max(2k, k+16)=21: {seen}"
 
 

@@ -4,9 +4,10 @@ Segments partition the object set, so per-segment match counts are complete
 and the cap-buffer merge is exact -- segmented search must return identical
 ids *and* counts to a monolithic index over the concatenated data, for every
 registered engine, every selection method, uneven segment sizes (including a
-segment smaller than k), after compaction, and through the streamed
-(multiload-host) path.  RetrievalService's old rebuild-on-add path is the
-oracle for the serving-layer invariant.
+segment smaller than k), after compaction, and with the segments held in
+host memory and streamed through the device (the paper's multiple loading).
+RetrievalService's old rebuild-on-add path is the oracle for the
+serving-layer invariant.
 """
 import os
 import subprocess
@@ -17,6 +18,7 @@ import numpy as np
 import pytest
 
 from repro.core import GenieIndex, SegmentedIndex, engines, merge
+from repro.core.segments import even_segments
 from repro.core.types import Engine, TopKMethod
 
 _SRC = os.path.join(os.path.dirname(__file__), "..", "src")
@@ -33,8 +35,8 @@ def _case(engine: Engine, n=101, q=4, seed=0):
     return model, raw, queries, mc
 
 
-def _segmented(engine, raw, mc, cuts=CUTS):
-    seg = SegmentedIndex(engine=engine, max_count=mc, use_kernel=False)
+def _segmented(engine, raw, mc, cuts=CUTS, **kw):
+    seg = SegmentedIndex(engine=engine, max_count=mc, use_kernel=False, **kw)
     for a, b in zip(cuts, cuts[1:]):
         seg.add(raw[a:b])
     return seg
@@ -62,11 +64,13 @@ def test_segmented_equals_monolithic(engine, method):
 
 @pytest.mark.parametrize("engine", ALL_ENGINES)
 def test_segmented_streamed_equals_monolithic(engine):
-    """The multiload-host streaming path over heterogeneous segment sizes."""
+    """Host-resident segments of heterogeneous sizes, streamed through the
+    device one at a time, search exactly like the monolithic index."""
     model, raw, queries, mc = _case(engine)
     mono = GenieIndex.build(engine, raw, max_count=mc, use_kernel=False)
-    seg = _segmented(engine, raw, mc)
-    got = seg.search_multiload(queries, k=9)
+    seg = _segmented(engine, raw, mc, host_resident=True)
+    assert all(isinstance(s.data, np.ndarray) for s in seg.segments)
+    got = seg.search(queries, k=9)
     _assert_same(got, mono.search(queries, k=9), engine.value)
 
 
@@ -151,8 +155,6 @@ def test_segmented_empty_and_bad_args():
     seg = SegmentedIndex(engine=Engine.EQ)
     with pytest.raises(ValueError, match=r"add\(\) first"):
         seg.search(np.zeros((1, 4), np.int32), k=1)
-    with pytest.raises(ValueError, match=r"add\(\) first"):
-        seg.search_multiload(np.zeros((1, 4), np.int32), k=1)
     with pytest.raises(ValueError, match="max_segments"):
         seg.compact(0)
 
@@ -265,12 +267,11 @@ def test_retrieval_service_rejects_row_count_mismatch(rng):
 
 @pytest.mark.parametrize("n_parts", [0, -1, -7])
 def test_search_multiload_rejects_bad_n_parts(n_parts, rng):
-    """n_parts=0 used to ZeroDivisionError and negatives were silently
-    accepted; both must raise a ValueError naming n_parts."""
-    model, raw, queries, mc = _case(Engine.EQ, n=20)
-    idx = GenieIndex.build(Engine.EQ, raw, use_kernel=False)
-    with pytest.raises(ValueError, match="n_parts"):
-        idx.search_multiload(queries, k=3, n_parts=n_parts)
+    """An even split into zero or a negative number of parts must raise a
+    ValueError naming the part count, never divide by zero or return an
+    empty layout."""
+    with pytest.raises(ValueError, match="n_segments"):
+        even_segments(20, n_parts)
 
 
 def test_build_seconds_measures_completed_build():
@@ -289,9 +290,9 @@ def test_build_seconds_measures_completed_build():
 # ---------------------------------------------------------------------------
 
 def test_distributed_segmented_layout_parity():
-    """A ragged (non-divisible) segmented corpus through the sharded search
-    step: concat_data pads to mesh divisibility and n_objects masks the pad
-    tail, so results equal the monolithic reference exactly."""
+    """A ragged (non-divisible) segmented corpus through a DISTRIBUTED plan:
+    concat_data pads to mesh divisibility and n_objects masks the pad tail,
+    so results equal the monolithic reference exactly."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = _SRC
@@ -299,6 +300,7 @@ def test_distributed_segmented_layout_parity():
     code = textwrap.dedent("""
         import numpy as np, jax, jax.numpy as jnp
         from repro.core import SegmentedIndex, distributed, engines, cpq
+        from repro.core import plan as plan_lib
         from repro.core.types import Engine, SearchParams
         from repro.launch import mesh as mesh_lib
 
@@ -315,13 +317,15 @@ def test_distributed_segmented_layout_parity():
             queries = model.prepare_queries(rawq)
             mx = seg.max_count
             params = SearchParams(k=7, max_count=mx, use_kernel=False)
-            step = distributed.make_search_step(mesh, params, eng,
-                                                n_objects=n_objects)
+            plan = plan_lib.plan_search(
+                eng, 7, mx, layout=plan_lib.Layout.DISTRIBUTED,
+                n_objects=n_objects, use_kernel=False,
+                mesh_axes=tuple(mesh.axis_names))
             dd = jax.device_put(data, distributed.data_sharding(mesh))
             qq = jax.tree_util.tree_map(
                 lambda x: jax.device_put(x, distributed.replicated(mesh, 2)),
                 queries)
-            res = step(dd, qq)
+            res = plan_lib.execute(plan, dd, qq, mesh=mesh)
             want = cpq.sort_select(
                 model.reference(model.prepare_data(raw), queries), params)
             assert np.array_equal(np.asarray(res.ids), np.asarray(want.ids)), eng

@@ -1,5 +1,8 @@
 """Multiple loading (paper section III-D) and merge invariants.
 
+Multiple loading is the SEGMENTED layout: the parts stream through the device
+one at a time and their candidate buffers merge exactly.
+
 Formerly hypothesis property tests; rewritten as seeded-random parametrized
 cases so the tier-1 suite runs on environments without hypothesis.
 """
@@ -7,12 +10,15 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import GenieIndex, cpq, match, merge, multiload
-from repro.core.types import SearchParams
+from repro.core import GenieIndex, SegmentedIndex, match, merge
+from repro.core import plan as plan_lib
+from repro.core.segments import even_segments
+from repro.core.types import Engine
 
 
 @pytest.mark.parametrize("case", range(10))
 def test_multiload_scan_equals_full_search(case):
+    """An even split into 1-6 host-streamed parts == the monolithic search."""
     draw = np.random.default_rng(5000 + case)
     n = int(draw.integers(20, 201))
     parts = int(draw.integers(1, 7))
@@ -21,16 +27,25 @@ def test_multiload_scan_equals_full_search(case):
     qs = draw.integers(0, 8, (3, 12)).astype(np.int32)
     idx = GenieIndex.build_lsh(sigs, use_kernel=False)
     full = idx.search(qs, k=k)
-    part = idx.search_multiload(qs, k=k, n_parts=parts)
-    assert np.array_equal(np.asarray(full.counts), np.asarray(part.counts))
+    seg = SegmentedIndex(engine=Engine.EQ, max_count=idx.max_count,
+                         use_kernel=False, host_resident=True)
+    for part in np.split(sigs, np.cumsum(even_segments(n, parts))[:-1]):
+        seg.add(part)
+    got = seg.search(qs, k=k)
+    assert np.array_equal(np.asarray(full.counts), np.asarray(got.counts))
+    assert np.array_equal(np.asarray(full.ids), np.asarray(got.ids))
 
 
 def test_multiload_host_loop_matches_scan(rng):
+    """A raw match callable planned SEGMENTED over host parts == the full
+    search."""
     sigs = rng.integers(0, 8, (120, 12)).astype(np.int32)
     qs = rng.integers(0, 8, (4, 12)).astype(np.int32)
-    params = SearchParams(k=5, max_count=12)
     parts = [sigs[i * 40 : (i + 1) * 40] for i in range(3)]
-    host = multiload.multiload_search_host(parts, jnp.asarray(qs), params, match.match_eq)
+    plan = plan_lib.plan_search(match.match_eq, 5, 12,
+                                layout=plan_lib.Layout.SEGMENTED,
+                                part_rows=(40, 40, 40))
+    host = plan_lib.execute(plan, parts, jnp.asarray(qs))
     idx = GenieIndex.build_lsh(sigs, use_kernel=False)
     full = idx.search(qs, k=5)
     assert np.array_equal(np.asarray(host.counts), np.asarray(full.counts))

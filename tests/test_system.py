@@ -4,9 +4,10 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import GenieIndex, TopKMethod
+from repro.core import GenieIndex, SegmentedIndex, TopKMethod
 from repro.core.lsh import e2lsh, rbh, tau_ann
 from repro.core.postings import PostingsIndex
+from repro.core.segments import even_segments
 from repro.data.pipeline import synthetic_points
 
 
@@ -57,7 +58,12 @@ def test_multiload_matches_single_load(rng):
     q = pts[:8] + 0.05
     qsigs = e2lsh.hash_points(params, jnp.asarray(q))
     full = idx.search(qsigs, k=6)
-    parts = idx.search_multiload(qsigs, k=6, n_parts=5)
+    seg = SegmentedIndex(engine=idx.engine, max_count=idx.max_count,
+                         use_kernel=idx.use_kernel, host_resident=True)
+    sigs = np.asarray(idx.data)
+    for part in np.split(sigs, np.cumsum(even_segments(len(sigs), 5))[:-1]):
+        seg.add(part)
+    parts = seg.search(qsigs, k=6)
     assert np.array_equal(np.asarray(full.counts), np.asarray(parts.counts))
 
 

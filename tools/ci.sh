@@ -15,7 +15,7 @@ export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 echo "--- genielint (static invariants; docs/CONTRACTS.md) ---"
 PYTHONPATH=".:$PYTHONPATH" python -m tools.genielint --json reports/lint.json
 
-# Fast lane: the engine x {reference,kernel} x {search,multiload} conformance
+# Fast lane: the engine x {reference,kernel} x {search,segmented} conformance
 # matrix runs first so an engine-contract break fails in minutes (the
 # distributed leg needs a multi-device subprocess and runs with the suite).
 echo "--- engine conformance matrix (fast lane) ---"
@@ -40,7 +40,7 @@ if [[ "${1:-}" == "--fast" ]]; then
     # (tests/test_plan.py's fast, non-subprocess lane already ran above)
     python -m pytest -x -q \
         tests/test_engines.py tests/test_engine_matrix.py tests/test_cpq.py \
-        tests/test_multiload.py tests/test_kernels.py tests/test_system.py
+        tests/test_streaming.py tests/test_kernels.py tests/test_system.py
 else
     # tier-1 verify command from ROADMAP.md
     python -m pytest -x -q

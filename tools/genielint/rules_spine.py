@@ -2,8 +2,9 @@
 
 `core/plan.execute` is the ONLY code in the system allowed to orchestrate
 selection and merging: match kernels -> pad mask -> select_topk ->
-merge_ragged / merge_topk.  Every other entry point (index, segments,
-multiload, distributed, serving) must build a `QueryPlan` and delegate, so
+merge_ragged / merge_topk.  Every other entry point (index, segments --
+whose SEGMENTED host loop is the paper's multiple loading -- the mesh path,
+serving) must build a `QueryPlan` and delegate, so
 the (count desc, id asc) ordering, the pad-never-in-topk mask, and the
 ragged per-part k clamp have exactly one implementation.
 

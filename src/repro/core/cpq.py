@@ -15,11 +15,13 @@ without any atomics:
   phase 2 (gate):       AT = min(t >= 1 : ZA[t] < k);  threshold = AT - 1
   phase 3 (hash table): two-class compaction (strict > threshold first, then
                         ties == threshold, each in id order) into a fixed
-                        buffer of size cap -- the Hash-Table analogue.  Each
-                        of the cap slots gathers its own object by a binary
-                        search on one prefix sum over the strict mask then
-                        the tie mask: O(N) prefix sums and O(cap * log N)
-                        gathers; no sort of N and no scatter over N.
+                        buffer of size cap -- the Hash-Table analogue --
+                        over blocks of 128 ids: a kernel counts each
+                        block's strict objects and ties; a prefix over
+                        blocks places each slot in its block; a DMA of
+                        that block's 128-id window gives its lane.  One
+                        read of the counts and cap windows per row; no
+                        sort of N, no scatter over N, no element gathers.
 
 Only the final cap-sized buffer (cap ~ 2k << N) is ordered, reproducing the
 paper's "scan the small HT once" property.  Exactness versus a full sort is
@@ -27,12 +29,12 @@ property-tested in tests/test_cpq.py.
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 
 from repro.core.types import SearchParams, TopKResult
+from repro.kernels import common, ops as kops
+from repro.kernels import cpq_compact as _cpq_compact
 from repro.runtime import tracing
 
 
@@ -81,7 +83,8 @@ def audit_threshold(hist: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray
 
 @tracing.scoped(tracing.COMPACT)
 def _compact_candidates(
-    counts: jnp.ndarray, threshold: jnp.ndarray, cap: int
+    counts: jnp.ndarray, threshold: jnp.ndarray, cap: int, *,
+    interpret: bool | None = None,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Two-class compaction into a cap-sized buffer per query.
 
@@ -90,29 +93,70 @@ def _compact_candidates(
     (== threshold) in id order (the paper breaks ties randomly), cut at cap.
     Returns (ids [Q, cap], vals [Q, cap]), empty slots marked id=-1, val=-1.
 
-    The buffer is filled by gathering, not by scattering the N objects.
-    One prefix sum runs over the strict mask followed by the tie mask, a
-    monotone row of length 2N whose first half counts the strict objects
-    up to each id and whose second half continues with the ties.  Slot j
-    holds the object at the first position where it reaches j + 1: a
-    strict object at that id in the first half, a tie at (position - N)
-    in the second.  A binary search finds it: O(N) prefix sums and
-    O(cap * log N) gathers per row.  A scatter over N would be serialised
-    per element on the TPU, though nearly every object lands in no slot.
+    Blocked, in three steps over blocks of 128 consecutive ids:
+
+      1. block counts: one kernel pass over the counts gives each block's
+         number of strict objects and of ties (kernels/cpq_compact.py);
+      2. place each slot: slot j wants the (j + 1)-th strict object while
+         j < #strict, else the (j + 1 - #strict)-th tie.  Running sums over
+         the totals of groups of 128 blocks give its group, then over that
+         group's 128 block counts (taken by a one-hot product on the MXU)
+         its block, and the rank left to find inside the block;
+      3. window rank: a second kernel copies each slot's block, a whole
+         128-id window, by DMA; the slot's object is the lane where the
+         running count of its class reaches that rank.
+
+    Steps 2 and 3 compare and sum over [Q, S, N / 16384] and [Q, S, 128]
+    (S = cap rounded up to 128): no search, no gather of single elements
+    and no scatter over N, which the TPU serialises per element, and
+    nothing of size [Q, N] beside the counts.
     """
     q, n = counts.shape
+    block = _cpq_compact.BLOCK
     c = counts.astype(jnp.int32)
-    thr = threshold[:, None]
-    both = jnp.concatenate([c > thr, c == thr], axis=-1)   # [Q, 2N]
-    rank = jnp.cumsum(both.astype(jnp.int32), axis=-1)
-    slot = jnp.broadcast_to(jnp.arange(cap, dtype=jnp.int32), (q, cap))
-    first = jax.vmap(functools.partial(jnp.searchsorted, side="left", method="scan"))
-    pos = first(rank, slot + 1)                          # == 2n past the last
-    idx = jnp.minimum(jnp.where(pos < n, pos, pos - n), n - 1).astype(jnp.int32)
-    filled = slot < rank[:, -1:]
-    out_ids = jnp.where(filled, idx, -1)
-    out_vals = jnp.where(filled, jnp.take_along_axis(c, idx, axis=-1), -1)
-    return out_ids, out_vals
+    thr = threshold.astype(jnp.int32)
+    strict, ties = kops.cpq_block_counts(c, thr, interpret=interpret)
+    n_strict = jnp.sum(strict, axis=(1, 2))[:, None]
+    slot = jnp.arange(common.ceil_to(cap, block), dtype=jnp.int32)[None, :]
+    is_strict = slot < n_strict
+    target = jnp.where(is_strict, slot + 1, slot + 1 - n_strict)
+
+    def pick(s, t):              # each slot's row of its own class
+        return jnp.where(is_strict[..., None], s, t)
+
+    group, left = _place(pick(jnp.sum(strict, -1)[:, None],
+                              jnp.sum(ties, -1)[:, None]), target)
+    onehot = jnp.arange(strict.shape[1]) == group[..., None]
+
+    def rows(x):
+        # the slot's group of 128 block counts; exact in bfloat16: each
+        # count is at most 128 and each output one count
+        return jnp.einsum("qsg,qgb->qsb", onehot.astype(jnp.bfloat16),
+                          x.astype(jnp.bfloat16),
+                          preferred_element_type=jnp.float32).astype(jnp.int32)
+
+    b, left = _place(pick(rows(strict), rows(ties)), left)
+    blk = jnp.minimum(group * block + b, -(-n // block) - 1)
+    win = kops.cpq_slot_windows(c, blk, interpret=interpret)   # [Q, S, 128]
+    # the lanes past N follow every object of the last block, so the rank
+    # is reached before them and they need no mask
+    t = thr[:, None, None]
+    lane, _ = _place(pick(win > t, win == t).astype(jnp.int32), left)
+    vals = jnp.sum(jnp.where(jnp.arange(block) == lane[..., None], win, 0),
+                   axis=-1)
+    filled = slot < n_strict + jnp.sum(ties, axis=(1, 2))[:, None]
+    out_ids = jnp.where(filled, blk * block + lane, -1)
+    out_vals = jnp.where(filled, vals, -1)
+    return out_ids[:, :cap], out_vals[:, :cap]
+
+
+def _place(cnt: jnp.ndarray, target: jnp.ndarray):
+    """Per slot, the first position p of its row cnt [Q, S, W] at which the
+    running sum reaches target [Q, S], and target less the sum before p."""
+    upto = jnp.cumsum(cnt, axis=-1)
+    before = upto < target[..., None]
+    pos = jnp.sum(before, axis=-1, dtype=jnp.int32)
+    return pos, target - jnp.max(jnp.where(before, upto, 0), axis=-1)
 
 
 def topk_from_candidates(ids: jnp.ndarray, vals: jnp.ndarray, k: int) -> tuple[jnp.ndarray, jnp.ndarray]:

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.kernels import common
 from repro.kernels import cosine_count as _cos
+from repro.kernels import cpq_compact as _cpq_compact
 from repro.kernels import cpq_hist as _cpq_hist
 from repro.kernels import ip_count as _ip
 from repro.kernels import match_count as _mc
@@ -352,3 +353,41 @@ def cpq_hist(
         interpret=common.use_interpret(interpret)
     )
     return out[:, : max_count + 1]
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def cpq_block_counts(
+    counts: jnp.ndarray,
+    threshold: jnp.ndarray,
+    *,
+    interpret: bool | None = None,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """c-PQ compaction, first read: (strict, ties) int32 [Q, G, 128], the
+    objects of each 128-id block with count > threshold[q] and
+    == threshold[q], block b at [q, b // 128, b % 128] and 0 past
+    cdiv(N, 128) (kernels/cpq_compact.py).  The count matrix is passed as
+    it is: ragged edge tiles are masked in the kernel."""
+    qn, nn = counts.shape
+    tq = common.pick_tile(qn, _cpq_compact.TILE_Q, 8, knob="tile_q")
+    tn = common.pick_tile(nn, _cpq_compact.TILE_N, _cpq_compact.BLOCK,
+                          knob="tile_n")
+    strict, ties = _cpq_compact.block_counts_pallas(
+        counts.astype(jnp.int32), threshold.astype(jnp.int32), tile_q=tq,
+        tile_n=tn, interpret=common.use_interpret(interpret))
+    return (strict.reshape(qn, -1, _cpq_compact.BLOCK),
+            ties.reshape(qn, -1, _cpq_compact.BLOCK))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def cpq_slot_windows(
+    counts: jnp.ndarray,
+    blocks: jnp.ndarray,
+    *,
+    interpret: bool | None = None,
+) -> jnp.ndarray:
+    """c-PQ compaction, second read: int32 [Q, S, 128], slot j of row q
+    the 128 counts of block blocks[q, j], ids 128 b .. 128 b + 127 (the
+    lanes past N hold no object).  S % 128 == 0."""
+    return _cpq_compact.slot_windows_pallas(
+        counts.astype(jnp.int32), blocks.astype(jnp.int32),
+        interpret=common.use_interpret(interpret))

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import cpq, merge, spq
 from repro.core.types import SearchParams
@@ -115,6 +116,9 @@ def test_merge_equals_global_topk(case):
     assert np.array_equal(np.asarray(res.counts), np.asarray(res2.counts))
 
 
+TPU_SIM = pltpu.InterpretParams(uninitialized_memory="zero")
+
+
 def _compact_by_scatter(counts, threshold, cap):
     """Reference: each object scattered to its slot, the N-wide formulation
     the gather form replaced (strict first, then ties, each in id order)."""
@@ -159,6 +163,33 @@ def _compaction_case(name, draw):
     if name == "rows_with_other_thresholds":
         counts = draw.integers(0, mx + 1, size=(5, 400))
         return counts, np.array([0, 3, 8, 14, 15]), 100
+    if name.startswith("n_"):             # around the 128-id block edges
+        q, n = (13, 3 * 128 + 1) if name == "n_385" else (3, int(name[2:]))
+        counts = draw.integers(0, 8, size=(q, n))
+        return counts, draw.integers(0, 8, size=q), 40
+    if name == "strict_in_last_partial_block":
+        counts = draw.integers(0, 5, size=(2, 3 * 128 + 50))
+        counts[:, 3 * 128 + draw.choice(50, 7, replace=False)] = 9
+        return counts, np.array([5, 5]), 16
+    if name == "ties_straddle_block_edge":   # the cap cuts block 1 mid-way
+        counts = draw.integers(0, 4, size=(2, 512))
+        counts[:, [10, 500]] = 9
+        counts[:, 100:160] = 5
+        counts[:, 250:300] = 5
+        return counts, np.array([5, 5]), 2 + 45
+    if name == "all_ties_several_blocks":
+        return np.full((2, 700), 7), np.array([7, 7]), 500
+    if name == "one_block_holds_all":
+        counts = draw.integers(0, 4, size=(3, 1000))
+        for row, start in enumerate([0, 256, 896]):
+            counts[row, start + draw.choice(104, 30, replace=False)] = 5
+            counts[row, start + draw.choice(104, 4, replace=False)] = 9
+        return counts, np.array([5, 5, 5]), 64
+    if name == "cap_above_total":
+        counts = draw.integers(0, 5, size=(2, 300))
+        counts[:, draw.choice(300, 10, replace=False)] = 5
+        counts[:, draw.choice(300, 3, replace=False)] = 8
+        return counts, np.array([5, 5]), 100
     q, n = int(draw.integers(1, 6)), int(draw.integers(1, 700))
     counts = draw.integers(0, mx + 1, size=(q, n))
     return counts, draw.integers(0, mx + 2, size=q), int(draw.integers(1, 120))
@@ -168,15 +199,20 @@ def _compaction_case(name, draw):
     "n_below_cap", "n_is_1", "all_ties", "no_ties", "threshold_0",
     "threshold_above_max", "ties_past_cap", "rows_with_other_thresholds",
     "random_0", "random_1", "random_2", "random_3",
+    "n_127", "n_128", "n_129", "n_385", "strict_in_last_partial_block",
+    "ties_straddle_block_edge", "all_ties_several_blocks",
+    "one_block_holds_all", "cap_above_total",
 ])
 def test_compaction_equals_scatter_formulation(name):
-    """The gathered buffer equals the scattered one bit for bit, ids and
-    counts, including the -1 padding and the id-order cut of ties at cap."""
+    """The blocked buffer equals the scattered one bit for bit, ids and
+    counts, including the -1 padding and the id-order cut of ties at cap.
+    Its kernels run in the TPU interpreter, whose reads past an array's
+    edge return 0 (a count in some class), so a missing edge mask fails."""
     draw = np.random.default_rng(5000 + zlib.crc32(name.encode()))
     counts, threshold, cap = _compaction_case(name, draw)
     counts = jnp.asarray(counts, dtype=jnp.int32)
     threshold = jnp.asarray(threshold, dtype=jnp.int32)
-    got = cpq._compact_candidates(counts, threshold, cap)
+    got = cpq._compact_candidates(counts, threshold, cap, interpret=TPU_SIM)
     want = _compact_by_scatter(counts, threshold, cap)
     for g, w in zip(got, want):
         assert g.dtype == w.dtype and g.shape == w.shape

@@ -14,9 +14,10 @@ import os
 import jax
 import jax.numpy as jnp
 import pytest
+from jax.experimental.layout import Format, Layout
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import autotune
+from repro.core import autotune, cpq
 from repro.core import plan as plan_lib
 from repro.core.types import Engine
 from repro.kernels import common, ops
@@ -100,6 +101,28 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     fn, shapes = KERNELS[name]
     compiled = _compile(fn, *(_spec(one_chip, s, dt) for s, dt in shapes))
     assert "tpu_custom_call" in compiled.as_text(), name
+
+
+# c-PQ compaction at a served dispatch: (Q, N) of the count matrix, cap 256
+COMPACTIONS = {"compact_adult": (128, 980_000), "compact_sift": (16, SEG_ROWS)}
+
+
+@pytest.mark.parametrize("name", sorted(COMPACTIONS))
+def test_compaction_needs_no_count_sized_temporary(name, one_chip):
+    """The blocked compaction (two kernels and [Q, cap, N / 128]-sized
+    work) keeps its temporaries far below the [Q, N] count matrix (Adult's
+    is 500 MB).  The counts come in row-major, as the match kernels write
+    them; an entry parameter left to the compiler would be laid out
+    column-major for a row that is no whole number of 128-lane tiles."""
+    q, n = COMPACTIONS[name]
+    counts = jax.ShapeDtypeStruct((q, n), jnp.int32,
+                                  sharding=Format(Layout((0, 1)), one_chip))
+    compiled = _compile(
+        lambda c, t: cpq._compact_candidates(c, t, 256, interpret=False),
+        counts, _spec(one_chip, (q,)))
+    text = compiled.as_text()
+    assert "cpq_block_counts" in text and "cpq_slot_windows" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
 @pytest.mark.parametrize("knob", ["tile_q", "tile_n"])
